@@ -85,9 +85,9 @@ def run_fused_kernels(n_lines=20000) -> list[dict]:
     delims = tuple(ord(c) for c in " \t,;:=")
     args = (jnp.asarray(blocks), jnp.asarray(blens),
             jnp.asarray(pws[0][0]), jnp.asarray(pws[1][0]))
-    tokenize_hash(*args, delims=delims)  # warm the jit cache
+    tokenize_hash(*args, delims=delims, interpret=ops.interpret())  # warm the jit cache
     t0 = time.time()
-    out = tokenize_hash(*args, delims=delims)
+    out = tokenize_hash(*args, delims=delims, interpret=ops.interpret())
     out[0].block_until_ready()
     dev_s = time.time() - t0
     rows.append({"impl": "tokenize_hash (pallas interpret)",

@@ -113,7 +113,7 @@ def _backends() -> dict:
     from repro.kernels import ops
 
     rep = ops.backend_report()
-    return {"interpret_mode": bool(ops.INTERPRET),
+    return {"interpret_mode": ops.interpret(),
             "backends": {op: info["backend"] for op, info in rep.items()}}
 
 

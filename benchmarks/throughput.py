@@ -406,7 +406,7 @@ def bench_device_pipeline(lines: list[str], fmt: str, n_chunks: int = 20) -> dic
         "n_lines": n,
         "n_chunks": (n + chunk - 1) // chunk,
         "lines_per_sec": round(n / wall, 1),
-        "interpret_mode": bool(ops.INTERPRET),
+        "interpret_mode": ops.interpret(),
         "backends": {op: info["backend"] for op, info in report.items()},
         "backend_fallbacks": {op: info["fallbacks"]
                               for op, info in report.items() if info["fallbacks"]},

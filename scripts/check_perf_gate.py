@@ -59,7 +59,7 @@ def main() -> int:
                          "(screens make it O(1) in archive length)")
     ap.add_argument("--require-compiled", action="store_true",
                     help="fail (not just annotate) when device_pipeline ran "
-                         "in Pallas INTERPRET mode — for environments that "
+                         "in Pallas interpret mode — for environments that "
                          "promise a real accelerator")
     args = ap.parse_args()
 
@@ -115,7 +115,7 @@ def main() -> int:
         # is just a printed line.
         if dp.get("interpret_mode"):
             print("::warning title=Pallas interpret mode::device_pipeline "
-                  "numbers were measured with INTERPRET=1 "
+                  "numbers were measured in Pallas interpret mode "
                   f"(backends: {dp.get('backends', {})}) — relative cost "
                   "only, not accelerator performance")
         if args.require_compiled:
@@ -125,7 +125,7 @@ def main() -> int:
             if dp.get("interpret_mode"):
                 failures.append(line)
         elif dp.get("interpret_mode"):
-            print("note  device_pipeline ran in Pallas INTERPRET mode "
+            print("note  device_pipeline ran in Pallas interpret mode "
                   f"(backends: {dp.get('backends', {})}) — its lines/sec "
                   "calibrates relative cost only, not accelerator perf")
         if dp.get("backend_fallbacks"):

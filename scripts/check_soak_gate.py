@@ -114,7 +114,7 @@ def main() -> int:
                   f"(cap {cap:.2f})", per1k > cap)
         if r.get("interpret_mode"):
             print("::warning title=Pallas interpret mode::soak "
-                  f"[{mode}] throughput/latency measured with INTERPRET=1 — "
+                  f"[{mode}] throughput/latency measured in Pallas interpret mode — "
                   "relative cost only, not accelerator performance")
 
     for c in checks:

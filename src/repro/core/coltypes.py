@@ -386,14 +386,17 @@ def _transform_numpy(arr: np.ndarray, kind: int) -> np.ndarray:
     return (np.abs(dd) << 1) - (dd < 0)
 
 
-def _transformed_stream(vals: list[int], kind: int, use_kernel: bool) -> list | np.ndarray:
+def _transformed_stream(vals: list[int], kind: int,
+                        use_kernel: bool | None) -> list | np.ndarray:
+    """``use_kernel=None`` takes the Pallas kernel on a TPU backend."""
     hi = max(abs(min(vals)), abs(max(vals)))
-    if use_kernel and hi < KERNEL_SAFE:
-        from repro.kernels.ops import delta_zigzag
+    if use_kernel is not False and hi < KERNEL_SAFE:
+        from repro.kernels.ops import delta_zigzag, on_tpu
 
-        return delta_zigzag(np.asarray([vals], np.int32),
-                            np.asarray([len(vals)], np.int32),
-                            np.asarray([kind], np.int32))[0, :len(vals)].astype(np.int64)
+        if use_kernel or on_tpu():
+            return delta_zigzag(np.asarray([vals], np.int32),
+                                np.asarray([len(vals)], np.int32),
+                                np.asarray([kind], np.int32))[0, :len(vals)].astype(np.int64)
     if hi < _INT64_SAFE:
         return _transform_numpy(np.asarray(vals, np.int64), kind)
     # arbitrary precision: object dtype keeps python ints exact all the
@@ -449,7 +452,7 @@ def _write_affixes(head: bytearray, info: dict) -> None:
 
 
 def encode_typed(name: str, values: list[str], uvals: list[str] | None = None,
-                 *, use_kernel: bool = False,
+                 *, use_kernel: bool | None = False,
                  wide_ints_text: bool = False) -> tuple[dict[str, bytes], dict] | None:
     """Typed encoding of one column -> ({objects}, summary), or None when
     the column classifies TEXT (caller falls back to the v1 layout).

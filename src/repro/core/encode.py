@@ -300,12 +300,13 @@ class ColumnCodec:
     on the presence of ``name.ct``. ``type_sink`` receives the per-column
     type summary (feeds ``meta["coltypes"]`` and the LZJS manifests);
     ``use_kernel`` routes the integer transforms through the Pallas
-    delta/zigzag kernel (byte-identical output).
+    delta/zigzag kernel (byte-identical output; ``None`` follows the
+    platform).
     """
 
     def __init__(self, name: str, paradict: ParamDict | None = None, *,
                  typed: bool = False, type_sink: dict | None = None,
-                 use_kernel: bool = False, wide_ints_text: bool = False):
+                 use_kernel: bool | None = False, wide_ints_text: bool = False):
         self.name = name
         self.paradict = paradict
         self.typed = typed

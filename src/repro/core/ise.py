@@ -27,7 +27,9 @@ class ISEConfig:
     max_iters: int = 5
     target_match_rate: float = 0.9  # paper: "empirically, 90%"
     seed: int = 0
-    use_kernel: bool = False      # route matching through the Pallas kernel
+    # route matching and the typed-column transforms through the Pallas
+    # kernels; None follows the platform (kernels on a TPU backend)
+    use_kernel: bool | None = None
     cluster: ClusterConfig = field(default_factory=ClusterConfig)
 
 

@@ -233,7 +233,7 @@ def match_first(
     ids: np.ndarray,
     lens: np.ndarray,
     templates: list[np.ndarray],
-    use_kernel: bool = False,
+    use_kernel: bool | None = False,
     dedup: bool = True,
 ) -> np.ndarray:
     """Assign each line the lowest-id matching template (-1 = none).
@@ -242,7 +242,8 @@ def match_first(
     root, so each line only runs the DP against plausible candidates.
     With ``dedup`` (default) duplicate (ids, len) rows are matched once
     and the assignment is broadcast back — bit-identical results, and the
-    DP only pays for distinct lines.
+    DP only pays for distinct lines. ``use_kernel=None`` runs the Pallas
+    kernel path on a TPU backend and the numpy path elsewhere.
     """
     n = ids.shape[0]
     assign = np.full((n,), -1, np.int32)
@@ -263,10 +264,11 @@ def match_first(
             )
             return sub[inv].astype(np.int32)
 
-    if use_kernel:
+    if use_kernel is not False:
         from repro.kernels import ops as kops
 
-        return kops.match_first_bucketed(ids, lens, templates)
+        if use_kernel or kops.on_tpu():
+            return kops.match_first_bucketed(ids, lens, templates)
 
     first_tok = ids[:, 0]
     for k, tpl in enumerate(templates):

@@ -147,7 +147,8 @@ def compress_parallel(
     chunk_lines: int | None = None,
     shared_store: bool = False,
 ) -> bytes:
-    """Compress with ``n_workers`` processes over line chunks.
+    """Compress with ``n_workers`` processes over line chunks (numpy
+    path in the workers: DESIGN.md §8).
 
     ``shared_store=True`` seeds one ``TemplateStore`` from a corpus
     sample and shares it across every chunk (match-only workers,
@@ -163,7 +164,11 @@ def compress_parallel(
     if n_workers <= 1 or len(chunks) == 1:
         blobs = _compress_chunks_pipelined(chunks, cfg)
     else:
-        blobs = _map_resilient(_compress_chunk, [(c, cfg) for c in chunks],
+        # one process per chip: workers take the numpy path and never
+        # touch JAX, so the device stays with this process (byte-identical
+        # archives either way)
+        host = replace(cfg, ise=replace(cfg.ise, use_kernel=False))
+        blobs = _map_resilient(_compress_chunk, [(c, host) for c in chunks],
                                n_workers)
     return frame_multi(blobs, seal=cfg.integrity)
 

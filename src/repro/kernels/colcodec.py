@@ -37,32 +37,38 @@ MONOTONE_INT = 1
 TIMESTAMP = 2
 NUMERIC = 3
 
-RN = 8  # rows (columns-under-transform) per tile
+RN = 8      # rows (columns-under-transform) per tile
+CW = 2048   # positions per tile; a longer row spans several tiles
 
 
-def _colcodec_kernel(vals_ref, lens_ref, mode_ref, ref_ref, out_ref):
-    v = vals_ref[...]                    # (RN, C) int32
-    lens = lens_ref[...][:, 0]           # (RN,)
-    mode = mode_ref[...][:, 0]
-    refv = ref_ref[...][:, 0]
-    rn, width = v.shape
+def _colcodec_kernel(vals_ref, prev_ref, lens_ref, mode_ref, ref_ref, out_ref):
+    v = vals_ref[...]                    # (RN, CW) int32
+    lens = lens_ref[...]                 # (RN, 1)
+    mode = mode_ref[...]
+    refv = ref_ref[...]
+    rn, cw = v.shape
+    j = pl.program_id(1)
 
-    pos = jax.lax.broadcasted_iota(jnp.int32, (rn, width), 1)
-    in_len = pos < lens[:, None]
+    pos = j * cw + jax.lax.broadcasted_iota(jnp.int32, (rn, cw), 1)
+    in_len = pos < lens
     vm = jnp.where(in_len, v, 0)
 
+    # the tile extended by the two values before it (the previous tile's
+    # last two; the first tile reads itself there, and the ``epos > 0``
+    # select below zeroes what they would contribute)
+    ext = jnp.concatenate([prev_ref[...][:, cw - 2:], vm], axis=1)   # (RN, CW+2)
+    epos = j * cw - 2 + jax.lax.broadcasted_iota(jnp.int32, (rn, cw + 2), 1)
     # first differences with t[0] = 0 (the first value rides in the
-    # descriptor, not the payload)
-    prev = jnp.concatenate([jnp.zeros((rn, 1), jnp.int32), vm[:, :-1]], axis=1)
-    d = jnp.where(pos > 0, vm - prev, 0)
+    # descriptor, not the payload); valid from extended index 1 on
+    shifted = jnp.concatenate([jnp.zeros((rn, 1), jnp.int32), ext[:, :-1]], axis=1)
+    d_ext = jnp.where(epos > 0, ext - shifted, 0)
+    d = d_ext[:, 2:]
     # second differences (dd[0] = 0, dd[1] = d[1]) + zigzag
-    dprev = jnp.concatenate([jnp.zeros((rn, 1), jnp.int32), d[:, :-1]], axis=1)
-    dd = d - dprev
+    dd = d - d_ext[:, 1:-1]
     zz = (dd << 1) ^ (dd >> 31)
 
-    fo = vm - refv[:, None]
-    out = jnp.where((mode == NUMERIC)[:, None], fo,
-                    jnp.where((mode == MONOTONE_INT)[:, None], d, zz))
+    fo = vm - refv
+    out = jnp.where(mode == NUMERIC, fo, jnp.where(mode == MONOTONE_INT, d, zz))
     out_ref[...] = jnp.where(in_len, out, 0).astype(jnp.uint32)
 
 
@@ -73,25 +79,33 @@ def colcodec_transform(
     mode: jnp.ndarray,
     ref: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """(R, C) int32 + per-row len/mode/ref -> (R, C) uint32 transforms."""
     record_trace("colcodec_transform")
     r, width = vals.shape
     r_pad = -r % RN
-    vals_p = jnp.pad(vals, ((0, r_pad), (0, 0)))
+    cw = width if width <= CW else CW
+    c_pad = -width % cw
+    vals_p = jnp.pad(vals, ((0, r_pad), (0, c_pad)))
+
     def col(a):
         return jnp.pad(a, ((0, r_pad),)).reshape(-1, 1)
+
+    def row(i, j):
+        return (i, 0)
+
     return pl.pallas_call(
         _colcodec_kernel,
-        out_shape=jax.ShapeDtypeStruct((r + r_pad, width), jnp.uint32),
-        grid=((r + r_pad) // RN,),
+        out_shape=jax.ShapeDtypeStruct(vals_p.shape, jnp.uint32),
+        grid=((r + r_pad) // RN, (width + c_pad) // cw),
         in_specs=[
-            pl.BlockSpec((RN, width), lambda i: (i, 0)),
-            pl.BlockSpec((RN, 1), lambda i: (i, 0)),
-            pl.BlockSpec((RN, 1), lambda i: (i, 0)),
-            pl.BlockSpec((RN, 1), lambda i: (i, 0)),
+            pl.BlockSpec((RN, cw), lambda i, j: (i, j)),
+            pl.BlockSpec((RN, cw), lambda i, j: (i, jnp.maximum(j - 1, 0))),
+            pl.BlockSpec((RN, 1), row),
+            pl.BlockSpec((RN, 1), row),
+            pl.BlockSpec((RN, 1), row),
         ],
-        out_specs=pl.BlockSpec((RN, width), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((RN, cw), lambda i, j: (i, j)),
         interpret=interpret,
-    )(vals_p, col(lens), col(mode), col(ref))[:r]
+    )(vals_p, vals_p, col(lens), col(mode), col(ref))[:r, :width]

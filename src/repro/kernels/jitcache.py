@@ -1,5 +1,6 @@
 """Shape-bucketing + trace accounting for the logzip kernels
-(DESIGN.md §10.3).
+(DESIGN.md §10.3), and the persistent compile cache the entry points
+turn on.
 
 ``jax.jit`` caches compiled executables by input *shape* — streaming
 chunks with drifting widths would re-trace (and on hardware recompile)
@@ -15,7 +16,9 @@ of re-traces/compiles — the throughput benchmark exports it and
 
 from __future__ import annotations
 
+import os
 from collections import Counter
+from pathlib import Path
 
 TRACE_COUNTS: Counter = Counter()
 CALL_COUNTS: Counter = Counter()
@@ -57,3 +60,34 @@ def reset_counters() -> None:
     TRACE_COUNTS.clear()
     CALL_COUNTS.clear()
     BUCKET_SHAPES.clear()
+
+
+# ------------------------------------------------ persistent compile cache
+
+# the kernels compile in about a second on a TPU, just under JAX's
+# default threshold (1 s) for writing an executable to the cache
+CACHE_MIN_COMPILE_SECS = 0.1
+
+
+def compile_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache`` at the
+    root of this checkout (a fixed path: the directory is part of every
+    cache key, so a moving one never hits)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Called by the entry points (``launch/compress.py``, ``chip_smoke.py``),
+    never at import. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has
+    already read it and no other directory is set here."""
+    import jax
+
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      CACHE_MIN_COMPILE_SECS)
+    return path

@@ -124,7 +124,7 @@ def match_extract(
     t_lens: jnp.ndarray,
     *,
     n_slots: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """-> (assign (N,) int32 lowest matching template id or -1,
     spans (N, n_slots, 2) int32 [start, end) per star slot).

@@ -1,7 +1,9 @@
 """jit'd wrappers + host/pod conveniences for the logzip kernels.
 
-``interpret`` defaults to True (this container is CPU-only); on a real
-TPU set REPRO_PALLAS_INTERPRET=0 to run the compiled kernels.
+The platform picks the path: on a TPU backend the Pallas kernels run
+compiled, anywhere else (the CPU test suite) they run in Pallas
+interpret mode. Nothing needs setting either way; ``python chip_smoke.py``
+drives the main path on a chip and checks it end to end.
 
 ``wildcard_match_sharded`` is the pod-scale matcher: logs sharded over
 the mesh ``data`` axis, templates replicated — zero-collective data
@@ -10,8 +12,8 @@ parallelism (the paper's "highly parallel matching" mapped onto a pod).
 
 from __future__ import annotations
 
+import functools
 import logging
-import os
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +33,24 @@ from .tokenize import hash_powers, tokenize_hash
 from .wildcard_match import STAR_ID
 from .wildcard_match import wildcard_match as _wildcard_match
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+@functools.cache
+def platform() -> str:
+    """The JAX backend the kernels run on, resolved at the first kernel
+    call (never at import, so importing this module touches no device)."""
+    return jax.default_backend()
+
+
+def on_tpu() -> bool:
+    """Whether the kernels run compiled on a TPU. This is the default of
+    every path selector that chooses between a kernel and its host twin
+    (``ISEConfig.use_kernel``, ``distinct_counts(prefer_host=)``)."""
+    return platform() == "tpu"
+
+
+def interpret() -> bool:
+    """Pallas interpret mode: everywhere but on a TPU."""
+    return not on_tpu()
 
 
 # ------------------------------------------ backend fallback (DESIGN §13)
@@ -42,8 +61,8 @@ INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 # tier is demoted for the rest of the process (no per-call retry storm)
 # and the demotion is logged once, structured, via the
 # ``repro.kernels.ops`` logger. ``backend_report()`` says which tier each
-# op is running on — the benchmark harness records it so device numbers
-# are never silently host numbers.
+# op is running on. On a TPU nothing is demoted: a kernel that fails
+# raises, so a device run can never silently be a host run.
 
 _LOG = logging.getLogger("repro.kernels.ops")
 _DEMOTED: dict[str, int] = {}  # op -> first chain tier still trusted
@@ -52,6 +71,8 @@ _FALLBACKS: dict[str, list[dict]] = {}  # op -> demotion events
 
 def _dispatch(op: str, *args, **kw):
     chain = _CHAINS[op]
+    if on_tpu():
+        return chain[0][1](*args, **kw)
     err: Exception | None = None
     for i in range(_DEMOTED.get(op, 0), len(chain)):
         backend, fn = chain[i]
@@ -62,7 +83,7 @@ def _dispatch(op: str, *args, **kw):
             _DEMOTED[op] = i + 1
             nxt = chain[i + 1][0] if i + 1 < len(chain) else None
             event = {"op": op, "backend": backend, "fallback": nxt,
-                     "interpret": INTERPRET,
+                     "interpret": interpret(),
                      "error": f"{type(e).__name__}: {e}"}
             _FALLBACKS.setdefault(op, []).append(event)
             if nxt is not None:
@@ -78,7 +99,7 @@ def backend_report() -> dict:
     out = {}
     for op, chain in _CHAINS.items():
         tier = min(_DEMOTED.get(op, 0), len(chain) - 1)
-        out[op] = {"backend": chain[tier][0], "interpret": INTERPRET,
+        out[op] = {"backend": chain[tier][0], "interpret": interpret(),
                    "fallbacks": list(_FALLBACKS.get(op, []))}
     return out
 
@@ -172,35 +193,35 @@ def _distinct_counts_host(inv, weights, n_bins: int) -> np.ndarray:
 
 _CHAINS: dict[str, tuple] = {
     "simcount": (
-        ("kernel", lambda lg, tp: _simcount(lg, tp, interpret=INTERPRET)),
+        ("kernel", lambda lg, tp: _simcount(lg, tp, interpret=interpret())),
         ("ref", lambda lg, tp: ref.simcount_ref(lg, tp)),
         ("host", lambda lg, tp: _simcount_host(lg, tp)),
     ),
     "wildcard_match": (
-        ("kernel", lambda *a: _wildcard_match(*a, interpret=INTERPRET)),
+        ("kernel", lambda *a: _wildcard_match(*a, interpret=interpret())),
         ("ref", lambda *a: ref.wildcard_match_ref(*a)),
         ("host", lambda *a: _wildcard_match_np(*a)),
     ),
     "match_extract": (
         ("kernel", lambda *a, n_slots: _match_extract(
-            *a, n_slots=n_slots, interpret=INTERPRET)),
+            *a, n_slots=n_slots, interpret=interpret())),
         # the jnp tier for the fused op IS the host anchor matcher
         ("host", lambda *a, n_slots: ref.match_extract_ref(*a, n_slots=n_slots)),
     ),
     "tokenize_hash": (
         ("kernel", lambda *a, delims: tokenize_hash(
-            *a, delims=delims, interpret=INTERPRET)),
+            *a, delims=delims, interpret=interpret())),
         ("ref", lambda *a, delims: ref.tokenize_hash_ref(*a, delims)),
         ("host", lambda *a, delims: _tokenize_hash_host(*a, delims=delims)),
     ),
     "colcodec_transform": (
-        ("kernel", lambda *a: _colcodec_transform(*a, interpret=INTERPRET)),
+        ("kernel", lambda *a: _colcodec_transform(*a, interpret=interpret())),
         ("ref", lambda *a: ref.colcodec_transform_ref(*a)),
         ("host", lambda *a: _colcodec_transform_host(*a)),
     ),
     "distinct_counts": (
         ("kernel", lambda iv, w, d: _scan_distinct_counts(
-            iv, w, n_bins=d, interpret=INTERPRET)[0]),
+            iv, w, n_bins=d, interpret=interpret())[0]),
         ("ref", lambda iv, w, d: ref.distinct_counts_ref(iv, w, d)),
         ("host", lambda iv, w, d: _distinct_counts_host(
             np.asarray(iv), np.asarray(w), d)),
@@ -356,8 +377,6 @@ def wildcard_match_sharded(logs, lens, templates, t_lens, mesh: Mesh, axis: str 
     shapes (``tests/test_jitcache.py`` pins the trace count at 1 across
     repeated same-shape calls).
     """
-    from jax.experimental.shard_map import shard_map
-
     from .jitcache import record_trace
 
     key = (mesh, axis)
@@ -365,14 +384,14 @@ def wildcard_match_sharded(logs, lens, templates, t_lens, mesh: Mesh, axis: str 
     if fn is None:
         def local(lg, ln, tp, tl):
             record_trace("wildcard_match_sharded")
-            return _wildcard_match(lg, ln[:, 0], tp, tl, interpret=INTERPRET)
+            return _wildcard_match(lg, ln[:, 0], tp, tl, interpret=interpret())
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(None, None), P(None, None)),
             out_specs=P(axis, None),
-            check_rep=False,
+            check_vma=False,
         ))
         _SHARDED_CACHE[key] = fn
     return fn(
@@ -470,19 +489,19 @@ def distinct_counts(inv, n_bins: int, weights=None, *,
     §14): ``out[b] = sum(weights[i] for inv[i] == b)`` -> (n_bins,) int32.
     ``weights=None`` counts occurrences. Bit-identical on every tier.
 
-    ``prefer_host`` defaults to ``INTERPRET`` — benchmark honesty: in
+    ``prefer_host`` defaults to ``not on_tpu()`` — benchmark honesty: in
     interpret mode the Pallas grid loop is pure-Python-slow, and routing
     the aggregation wall clock through it would report numbers that are
-    neither host nor accelerator performance. On a real device
-    (``REPRO_PALLAS_INTERPRET=0``) the kernel path is the default; tests
-    force ``prefer_host=False`` to exercise the full dispatch chain.
+    neither host nor accelerator performance. On a TPU the kernel path is
+    the default; tests force ``prefer_host=False`` to exercise the full
+    dispatch chain.
     """
     inv_np = np.asarray(inv, np.int64)
     n = inv_np.shape[0]
     w_np = np.ones(n, np.int32) if weights is None \
         else np.asarray(weights, np.int32)
     if prefer_host is None:
-        prefer_host = INTERPRET
+        prefer_host = not on_tpu()
     if prefer_host or n == 0 or n_bins == 0:
         return _distinct_counts_host(inv_np, w_np, n_bins)
     nb, db = bucket(n, 256), bucket(n_bins, 128)

@@ -82,7 +82,7 @@ def _simcount_kernel(logs_ref, tmpl_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def simcount(logs: jnp.ndarray, templates: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def simcount(logs: jnp.ndarray, templates: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """(N, T) x (K, Tt) int32 -> (N, K) int32 common-token counts."""
     n, t = logs.shape
     k, tt = templates.shape

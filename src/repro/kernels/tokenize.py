@@ -58,6 +58,16 @@ def hash_powers(b: int) -> tuple:
     return tuple(out)
 
 
+def _prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along axis 1 by log-step shifted adds
+    (Mosaic does not lower ``cumsum``); exact in wrapping uint32."""
+    s = 1
+    while s < x.shape[1]:
+        x = x + jnp.concatenate([jnp.zeros((x.shape[0], s), x.dtype), x[:, :-s]], axis=1)
+        s *= 2
+    return x
+
+
 def _tokenize_kernel(delims: tuple, bytes_ref, lens_ref, pw1_ref, pw2_ref,
                      mask_ref, starts_ref, pref1_ref, pref2_ref):
     b = bytes_ref[...]              # (BN, B) uint8 (int32-widened below)
@@ -70,15 +80,15 @@ def _tokenize_kernel(delims: tuple, bytes_ref, lens_ref, pw1_ref, pw2_ref,
     is_delim = jnp.zeros((bn, width), jnp.bool_)
     for d in delims:                # static byte set -> unrolled compares
         is_delim = is_delim | (bi == d)
-    tok = in_len & ~is_delim
-    prev = jnp.concatenate([jnp.zeros((bn, 1), jnp.bool_), tok[:, :-1]], axis=1)
-    starts = tok & ~prev
+    tok = jnp.where(in_len & ~is_delim, 1, 0)   # int32: Mosaic shifts no i1 vectors
+    prev = jnp.concatenate([jnp.zeros((bn, 1), jnp.int32), tok[:, :-1]], axis=1)
+    starts = tok * (1 - prev)
 
     toki = tok.astype(jnp.uint32)
     for pw_ref, pref_ref in ((pw1_ref, pref1_ref), (pw2_ref, pref2_ref)):
-        pw = pw_ref[...][0]         # (B,) uint32
-        w = (bi.astype(jnp.uint32) + 1) * pw[None, :] * toki
-        pref_ref[...] = jnp.cumsum(w, axis=1, dtype=jnp.uint32)
+        pw = pw_ref[...]            # (1, B) uint32
+        w = (bi.astype(jnp.uint32) + 1) * pw * toki
+        pref_ref[...] = _prefix_sum(w)
     mask_ref[...] = tok.astype(jnp.int8)
     starts_ref[...] = starts.astype(jnp.int8)
 
@@ -91,7 +101,7 @@ def tokenize_hash(
     pw2: jnp.ndarray,
     *,
     delims: tuple,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(N, B) uint8 blocks -> (mask, starts, pref1, pref2); see module
     docstring for the layout contract."""

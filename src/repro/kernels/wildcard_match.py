@@ -2,29 +2,35 @@
 
 The paper's prefix tree compares one log against all templates in one
 pass on a CPU. The TPU-native equivalent (DESIGN.md §2) is the dense
-reachability DP over (log-block x template-block) tiles:
+reachability DP over (template-block x log-block) tiles:
 
     col[i] <- prev[i-1] & (log_i == t_j)     (literal t_j)
     col[i] <- OR_{i'<i} prev[i']             (t_j == '*', absorbs >= 1)
 
-The kernel carries the DP columns of ALL BK templates at once as one
-(BN, BK, T+1) tile and advances every template by one token per step:
-each of the Tt steps is a single branch-free VPU update (cumsum + shift
-+ compare + select) over the whole tile, instead of the BK serialized
-per-template passes of the naive formulation — the template axis is data
-parallelism, not a loop. Templates shorter than Tt freeze their column
-via the ``j < t_len`` select; a ``t_len < 0`` sentinel (padding rows,
+The kernel carries the DP columns of BK templates for BN lines at once
+as one ``(T+1, BK, BN)`` int32 tile and advances every template by one
+token per step: each of the Tt steps is a branch-free VPU update over
+the whole tile. Lines sit on the lanes and templates on the sublanes,
+so one ``(BK, BN)`` slab per log position is a whole number of vregs,
+and the position axis is the leading (untiled) one: shifting along it
+only renames vregs. The star's prefix-OR is built from log-step shifted
+ORs (Mosaic has no ``cumsum``). Template token ``j`` is read from the
+ref (``tmpl_ref[j]``, a ``(BK, 1)`` column), never by slicing a loaded
+value. Templates shorter than Tt freeze their column via the
+``j < t_len`` select; a ``t_len < 0`` sentinel (padding rows,
 over-length templates from ``ops.pack_templates``) matches nothing.
 
 PAD tokens (id 0) can never equal a template literal (ids >= 2), so no
 per-position masking is needed: correctness only requires reading the
 column at exactly i = len(log).
 
-Outputs int8 {0,1} (TPU has no bool memory type); ops.py exposes bool.
+The kernel writes a lane-dense ``(K, N)`` int32 matrix; the wrapper
+returns its transpose as int8 {0,1} (TPU has no bool memory type).
 
-VMEM per program (BN=256, BK=8, T=128):
-  logs 128 KiB + templates + the (BN, BK, T+1) int32 column tile ~1 MiB
-  + one (BN, BK, T) compare tile ~1 MiB — well inside ~16 MiB/core.
+VMEM per program (BN=128, BK=16): the column tile is (T+1) x 8 KiB,
+about 1 MiB at the widest token bucket (T=128), plus a few temporaries
+of the same size — independent of the number of templates, which the
+grid tiles. ``tests/test_tpu_compile.py`` compiles it for a v5e core.
 """
 
 from __future__ import annotations
@@ -38,41 +44,42 @@ from jax.experimental import pallas as pl
 PAD_ID = 0
 STAR_ID = 1
 
-BN = 256  # logs per tile
-BK = 8    # templates per tile
+BN = 128  # lines per tile (lanes)
+BK = 16   # templates per tile (sublanes)
 
 
 def _match_kernel(logs_ref, lens_ref, tmpl_ref, tlen_ref, out_ref):
-    logs = logs_ref[...]            # (BN, T)
-    lens = lens_ref[...][:, 0]      # (BN,)
-    tmpl = tmpl_ref[...]            # (BK, Tt)
-    tlens = tlen_ref[...][:, 0]     # (BK,)
-    bn, t = logs.shape
-    bk, tt = tmpl.shape
+    logs = logs_ref[...]            # (T, BN) token position x line
+    lens = lens_ref[...]            # (1, BN)
+    tlens = tlen_ref[...]           # (BK, 1)
+    t, bn = logs.shape
+    bk = tlens.shape[0]
+    tt = tmpl_ref.shape[0]
 
-    def per_token(j, col):          # col: (BN, BK, T+1) int32 reachability
-        tj = tmpl[:, j]                                   # (BK,)
-        is_star = (tj == STAR_ID)[None, :, None]
+    def shift(x, s):                # x[i - s] along the position axis, 0 below
+        return jnp.concatenate([jnp.zeros((s,) + x.shape[1:], x.dtype), x[:-s]], axis=0)
+
+    def per_token(j, col):          # col: (T+1, BK, BN) int32 reachability
+        tj = tmpl_ref[j][None]      # (1, BK, 1)
         # star: prefix-OR then shift right by one (absorbs >= 1 token)
-        run = jnp.minimum(jnp.cumsum(col, axis=2), 1)
-        zero = jnp.zeros((bn, bk, 1), col.dtype)
-        star_col = jnp.concatenate([zero, run[:, :, :-1]], axis=2)
+        run = col
+        s = 1
+        while s <= t:
+            run = run | shift(run, s)
+            s *= 2
+        star_col = shift(run, 1)
         # literal: advance where the log token equals this template token
-        lit = (logs[:, None, :] == tj[None, :, None]).astype(col.dtype)  # (BN, BK, T)
-        lit_col = jnp.concatenate([zero, col[:, :, :-1] * lit], axis=2)
-        new = jnp.where(is_star, star_col, lit_col)
-        active = (j < tlens)[None, :, None]               # template still has tokens
-        return jnp.where(active, new, col)
+        lit = (logs[:, None, :] == tj).astype(jnp.int32)          # (T, BK, BN)
+        lit_col = jnp.concatenate([jnp.zeros((1, bk, bn), jnp.int32), col[:-1] * lit], axis=0)
+        new = jnp.where(tj == STAR_ID, star_col, lit_col)
+        return jnp.where(j < tlens[None], new, col)               # template still has tokens
 
-    pos = jax.lax.broadcasted_iota(jnp.int32, (bn, bk, t + 1), 2)
-    col0 = (pos == 0).astype(jnp.int32)
-    col = jax.lax.fori_loop(0, tt, per_token, col0)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (t + 1, bk, bn), 0)
+    col = jax.lax.fori_loop(0, tt, per_token, (pos == 0).astype(jnp.int32))
 
-    at_len = (pos == lens[:, None, None]).astype(jnp.int32)
-    hit = (col * at_len).sum(axis=2)                      # col[i = len(log)]
-    hit = hit * (lens <= t).astype(jnp.int32)[:, None]    # truncated lines: no match
-    hit = hit * (tlens >= 0).astype(jnp.int32)[None, :]   # sentinel templates: no match
-    out_ref[...] = hit.astype(jnp.int8)
+    hit = jnp.sum(jnp.where(pos == lens[None], col, 0), axis=0)  # col[i = len(log)]
+    hit = hit * (lens <= t).astype(jnp.int32)                    # truncated lines: no match
+    out_ref[...] = hit * (tlens >= 0).astype(jnp.int32)          # sentinel templates: no match
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -82,7 +89,7 @@ def wildcard_match(
     templates: jnp.ndarray,
     t_lens: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """(N,T),(N,) x (K,Tt),(K,) int32 -> (N, K) int8 {0,1} match matrix.
 
@@ -96,21 +103,21 @@ def wildcard_match(
     k, tt = templates.shape
     n_pad = -n % BN
     k_pad = -k % BK
-    logs_p = jnp.pad(logs, ((0, n_pad), (0, 0)))
-    lens_p = jnp.pad(lens, ((0, n_pad),)).reshape(-1, 1)
-    tmpl_p = jnp.pad(templates, ((0, k_pad), (0, 0)))
+    logs_t = jnp.pad(logs, ((0, n_pad), (0, 0))).T                      # (T, Np)
+    lens_p = jnp.pad(lens, ((0, n_pad),)).reshape(1, -1)                # (1, Np)
+    tmpl_t = jnp.pad(templates, ((0, k_pad), (0, 0))).T[:, :, None]     # (Tt, Kp, 1)
     tlen_p = jnp.pad(t_lens, ((0, k_pad),), constant_values=-1).reshape(-1, 1)
     out = pl.pallas_call(
         _match_kernel,
-        out_shape=jax.ShapeDtypeStruct((n + n_pad, k + k_pad), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((k + k_pad, n + n_pad), jnp.int32),
         grid=((n + n_pad) // BN, (k + k_pad) // BK),
         in_specs=[
-            pl.BlockSpec((BN, t), lambda i, j: (i, 0)),
-            pl.BlockSpec((BN, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((BK, tt), lambda i, j: (j, 0)),
+            pl.BlockSpec((t, BN), lambda i, j: (0, i)),
+            pl.BlockSpec((1, BN), lambda i, j: (0, i)),
+            pl.BlockSpec((tt, BK, 1), lambda i, j: (0, j, 0)),
             pl.BlockSpec((BK, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((BN, BK), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((BK, BN), lambda i, j: (j, i)),
         interpret=interpret,
-    )(logs_p, lens_p, tmpl_p, tlen_p)
-    return out[:n, :k]
+    )(logs_t, lens_p, tmpl_t, tlen_p)
+    return out[:k, :n].T.astype(jnp.int8)

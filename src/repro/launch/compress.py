@@ -561,7 +561,7 @@ def _cmd_inspect(args) -> None:
         print("  ", t)
 
 
-def main():
+def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
     p = sub.add_parser("pack", help="batch compress a file ('-' = stdin)")
@@ -702,8 +702,11 @@ def main():
     cp.add_argument("--strict", action="store_true",
                     help="exit 3 when any input lines were lost")
     cp.add_argument("--json", action="store_true", help="report as JSON")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.kernels.jitcache import enable_compile_cache
+
+    enable_compile_cache()
     try:
         {"pack": _cmd_pack, "stream": _cmd_stream, "unpack": _cmd_unpack,
          "inspect": _cmd_inspect, "grep": _cmd_grep, "agg": _cmd_agg,

@@ -14,6 +14,12 @@ forces 512 host devices via XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple:
+    """Auto axes: the sharding rules rely on implicit (GSPMD) propagation."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,14 +35,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (dryrun.py does this)."
         )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, _auto(len(axes)), devices=devices)
 
 
 def make_local_mesh(model_parallel: int = 1, axes=("data", "model")):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), axes)
+    return jax.make_mesh((n // model_parallel, model_parallel), axes, _auto(len(axes)))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -74,7 +74,6 @@ def make_train_step_explicit(cfg, mesh, hyper: AdamWHyper | None = None, compres
     place the gradient reduction by hand). Gradients: psum over "data"
     (fp32, ICI) then error-feedback int8 psum over "pod" (DCN).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.compress import allreduce_int8
@@ -103,7 +102,7 @@ def make_train_step_explicit(cfg, mesh, hyper: AdamWHyper | None = None, compres
 
     def step(params, opt_state, err, batch):
         batch_specs = jax.tree.map(lambda x: P(dp, *(None,) * (x.ndim - 1)), batch)
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -118,7 +117,7 @@ def make_train_step_explicit(cfg, mesh, hyper: AdamWHyper | None = None, compres
                 jax.tree.map(lambda _: P(), err),
                 {"loss": P(), "grad_norm": P()},
             ),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, err, batch)
 
     return step

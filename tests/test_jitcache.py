@@ -115,3 +115,25 @@ def test_streaming_session_zero_recompiles_after_warmup():
         "kernel re-traced after warmup", traces_after_warmup,
         dict(jitcache.TRACE_COUNTS))
     assert LZJSReader(io.BytesIO(buf.getvalue())).read_all() == lines
+
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch):
+    from pathlib import Path
+
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert jitcache.compile_cache_dir() == "/some/dir"
+    before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        # JAX's own reading of the variable wins: no other dir is set
+        assert jitcache.enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == \
+            jitcache.CACHE_MIN_COMPILE_SECS
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = Path(__file__).resolve().parents[1]
+    assert jitcache.compile_cache_dir() == str(checkout / ".jax_cache")

@@ -157,3 +157,49 @@ def test_match_first_dedup_rows_identical():
     a_dd = match_first(logs, lens, templates, dedup=True)
     a_no = match_first(logs, lens, templates, dedup=False)
     np.testing.assert_array_equal(a_dd, a_no)
+
+
+# -------- the platform picks the path; a TPU never demotes a kernel --------
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_dispatch_demotes_only_off_tpu(monkeypatch, platform):
+    def refused(*a):
+        raise NotImplementedError("lowering refused")
+
+    monkeypatch.setattr(ops, "platform", lambda: platform)
+    monkeypatch.setitem(ops._CHAINS, "wildcard_match",
+                        (("kernel", refused), ("ref", lambda *a: "ref ran")))
+    ops.reset_backend_state()
+    try:
+        if platform == "tpu":
+            with pytest.raises(NotImplementedError, match="lowering refused"):
+                ops._dispatch("wildcard_match", 1)
+            rep = ops.backend_report()["wildcard_match"]
+            assert rep == {"backend": "kernel", "interpret": False, "fallbacks": []}
+        else:
+            assert ops._dispatch("wildcard_match", 1) == "ref ran"
+            rep = ops.backend_report()["wildcard_match"]
+            assert rep["backend"] == "ref" and rep["interpret"]
+            assert rep["fallbacks"][0]["error"].startswith("NotImplementedError")
+    finally:
+        ops.reset_backend_state()
+
+
+@pytest.mark.parametrize("platform,kernel_path", [("cpu", False), ("tpu", True)])
+def test_default_use_kernel_follows_platform(monkeypatch, platform, kernel_path):
+    from repro.core import coltypes
+
+    calls = []
+    monkeypatch.setattr(ops, "platform", lambda: platform)
+    monkeypatch.setattr(ops, "match_first_bucketed",
+                        lambda ids, lens, tpls: calls.append("match") or
+                        np.zeros(len(ids), np.int32))
+    monkeypatch.setattr(ops, "delta_zigzag",
+                        lambda v, ln, m: calls.append("delta") or
+                        np.zeros(v.shape, np.uint32))
+    rng = np.random.default_rng(2)
+    logs, lens, tmpl, tlens = _rand_case(rng, 20, 6, 3, 4)
+    match_first(logs, lens, [tmpl[i, : tlens[i]] for i in range(3)],
+                use_kernel=None, dedup=False)
+    coltypes._transformed_stream([5, 7, 9], coltypes.MONOTONE_INT, None)
+    assert calls == (["match", "delta"] if kernel_path else [])

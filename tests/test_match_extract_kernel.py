@@ -87,7 +87,7 @@ def test_match_extract_overlength_template_sentinel():
     tmpl, tlens = ops.pack_templates([np.array([2, 3, 4, 5, 6], np.int32)], t_max=3)
     assert tlens.tolist() == [-1]
     a, _sp = me_kernel(jnp.asarray(logs), jnp.asarray(lens), jnp.asarray(tmpl),
-                       jnp.asarray(tlens), n_slots=1)
+                       jnp.asarray(tlens), n_slots=1, interpret=ops.interpret())
     assert (np.asarray(a) == -1).all(), "over-length sentinel must match nothing"
 
 
@@ -133,7 +133,8 @@ def test_tokenize_hash_kernel_matches_ref():
     pws = hash_powers(blocks.shape[1])
     delims = tuple(ord(c) for c in DELIMS)
     got = tokenize_hash(jnp.asarray(blocks), jnp.asarray(blens),
-                        jnp.asarray(pws[0][0]), jnp.asarray(pws[1][0]), delims=delims)
+                        jnp.asarray(pws[0][0]), jnp.asarray(pws[1][0]), delims=delims,
+                        interpret=ops.interpret())
     want = ops.tokenize_hash_ref(blocks, blens, pws[0][0], pws[1][0], delims)
     for g, w, name in zip(got, want, ["mask", "starts", "pref1", "pref2"]):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=name)

@@ -42,7 +42,7 @@ def test_gspmd_train_step_matches_single_device():
     # single device reference
     p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
     install_mesh(mesh)
     ps = to_shardings(param_pspecs(params, cfg, mesh), mesh)
     os_ = {"mu": ps, "nu": ps, "step": NamedSharding(mesh, P())}
@@ -59,11 +59,10 @@ def test_gspmd_train_step_matches_single_device():
 def test_int8_pod_allreduce_error_feedback():
     out = run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import allreduce_int8, init_error_state
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"), (jax.sharding.AxisType.Auto,) * 2)
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(4, 64, 64)).astype(np.float32))}
 
     def body(gr, err):
@@ -71,8 +70,8 @@ def test_int8_pod_allreduce_error_feedback():
         red, err = allreduce_int8(local, err, "pod")
         return red, err
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")),
+                   check_vma=False)
     err = init_error_state(g)
     out1, err1 = fn(g, err)
     # reference mean over pod axis
@@ -98,7 +97,7 @@ def test_sharded_matching_no_collectives():
     out = run_py("""
     import jax, numpy as np, jax.numpy as jnp, re
     from repro.kernels import ops
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = jax.make_mesh((8, 1), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     logs = rng.integers(2, 20, (64, 8)).astype(np.int32)
     lens = np.full((64,), 8, np.int32)
@@ -122,14 +121,14 @@ def test_elastic_checkpoint_reshard():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.ckpt import save_checkpoint, load_checkpoint
 
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = jax.make_mesh((8,), ("data",), (jax.sharding.AxisType.Auto,))
     x = jnp.arange(64.0).reshape(8, 8)
     xs = jax.device_put(x, NamedSharding(mesh8, P("data", None)))
     d = tempfile.mkdtemp()
     save_checkpoint(d, 1, {"x": xs})
 
     # restore onto a DIFFERENT mesh shape (elastic restart 8 -> 2x4)
-    mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh24 = jax.make_mesh((2, 4), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
     sh = {"x": NamedSharding(mesh24, P("model", "data"))}
     tree, _, _ = load_checkpoint(d, shardings=sh)
     np.testing.assert_array_equal(np.asarray(tree["x"]), np.asarray(x))
@@ -155,7 +154,7 @@ def test_dryrun_cell_smoke():
     from repro.launch.hlo_cost import analyze
 
     cfg = get_config("jamba-v0.1-52b").reduced()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
     install_mesh(mesh)
     params_s = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
     opt_s = jax.eval_shape(adamw_init, params_s)

@@ -12,16 +12,9 @@ from repro.configs import ARCH_IDS, get_config
 from repro.distributed.sharding import batch_pspecs, cache_pspecs, param_pspecs
 from repro.models import init_cache, init_params, tp_pad
 
-def _abstract_mesh(sizes, names):
-    """jax >= 0.5 takes (sizes, names); jax 0.4.x takes (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
 
-
-MESH_1POD = _abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_prod(mesh, entry):
