@@ -1,0 +1,8 @@
+"""Share of ``wildcard_match``'s roofline over the window (%): the least time
+the chip needs for the logical work of every call
+(``roofline/wildcard_match.py``, ``peaks.json``) over the kernel's device time
+in the trace."""
+
+
+def read(rec):
+    return rec.roofline_pct("wildcard_match")
