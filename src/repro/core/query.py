@@ -44,6 +44,7 @@ from .codec import ChunkReader, FILE_MAGIC, open_container
 from .coltypes import INT_RE as _PARAM_INT_RE
 from .coltypes import canonical_int as _canonical_int
 from .coltypes import int_value_realizable as _int_value_realizable
+from .timing import StageTimer
 from .tokenizer import DEFAULT_DELIMITERS, LogFormat
 
 try:  # Python >= 3.11
@@ -1053,10 +1054,14 @@ class _ArchiveChunks:
     ``salvage=True`` (LZJS only) opens the container through the
     scan-rebuilt index, so queries keep working over the surviving
     chunks of a damaged archive. Quarantined chunks are skipped in
-    either mode — same semantics as ``LZJSReader.read_range``."""
+    either mode — same semantics as ``LZJSReader.read_range``.
+    LZJF / LZJM chunks are decoded while iterating, in ``tm``'s
+    ``query.decode``."""
 
-    def __init__(self, src, *, salvage: bool = False):
+    def __init__(self, src, *, salvage: bool = False,
+                 tm: StageTimer | None = None):
         self.salvage = salvage
+        self.tm = tm or StageTimer(None)
         self.reader = None
         blob = None
         if isinstance(src, (bytes, bytearray, memoryview)):
@@ -1134,7 +1139,8 @@ class _ArchiveChunks:
                     except Exception as e:
                         raise ValueError(
                             f"truncated or corrupt logzip chunk {k}: {e}") from e
-                cr = open_fn()
+                with self.tm("query.decode"):
+                    cr = open_fn()
                 yield (k, line_start, cr.n, None, lambda cr=cr: cr, None)
                 line_start += cr.n
 
@@ -1156,7 +1162,16 @@ class QueryStats:
     Bloom-filter tests; ``bloom_false_positives`` the chunks a Bloom pass
     opened that held no hit (observed FPP = fp / passes).
     ``chunks_counted_from_manifest`` are chunks ``count`` resolved from
-    their manifest EventID histogram without opening."""
+    their manifest EventID histogram without opening.
+
+    ``stage_times``: a dict, or None (the default, which costs nothing),
+    that receives inclusive seconds by phase through a ``StageTimer``:
+    ``query.screen`` (manifest and screen tests, Bloom probes included),
+    ``query.decode`` (opening a chunk), ``query.filter`` (the per-chunk
+    predicate evaluation), ``query.materialize`` (assembling and testing
+    lines, timed once per chunk) and ``query.aggregate`` (distinct decode
+    and ``distinct_counts``); the kernel wrappers add their
+    ``dispatch.<kernel>`` spans."""
 
     chunks_total: int = 0
     chunks_skipped: int = 0
@@ -1169,6 +1184,7 @@ class QueryStats:
     bloom_passes: int = 0
     bloom_false_positives: int = 0
     chunks_counted_from_manifest: int = 0
+    stage_times: dict | None = None
 
     @property
     def fraction_chunks_decoded(self) -> float:
@@ -1195,7 +1211,8 @@ def _validate_preds(preds, fmt) -> None:
 def _execute(src, query, stats: QueryStats, *, want_lines: bool = True,
              salvage: bool = False, count_from_manifest: bool = False):
     preds = _flatten(query)
-    arch = _ArchiveChunks(src, salvage=salvage)
+    tm = StageTimer(stats.stage_times)
+    arch = _ArchiveChunks(src, salvage=salvage, tm=tm)
     try:
         fmt = LogFormat(arch.fmt_str) if arch.fmt_str else None
         ctx = _Ctx(fmt, arch.session_templates, arch.session_params,
@@ -1204,9 +1221,10 @@ def _execute(src, query, stats: QueryStats, *, want_lines: bool = True,
         for k, line_start, n_lines, manifest, open_fn, screen_fn in arch.chunks():
             stats.chunks_total += 1
             outcome: dict = {}
-            possible = all(_chunk_possible(p, ctx, manifest, line_start,
-                                           n_lines, screen_fn, outcome)
-                           for p in preds)
+            with tm("query.screen"):
+                possible = all(_chunk_possible(p, ctx, manifest, line_start,
+                                               n_lines, screen_fn, outcome)
+                               for p in preds)
             stats.bloom_probes += outcome.get("bloom_probes", 0)
             stats.bloom_passes += outcome.get("bloom_passes", 0)
             if not possible:
@@ -1215,7 +1233,8 @@ def _execute(src, query, stats: QueryStats, *, want_lines: bool = True,
                 stats.chunks_skipped_by[r] = stats.chunks_skipped_by.get(r, 0) + 1
                 continue
             if count_from_manifest and manifest:
-                cn = _count_fast_chunk(preds, ctx, manifest, line_start, n_lines)
+                with tm("query.screen"):
+                    cn = _count_fast_chunk(preds, ctx, manifest, line_start, n_lines)
                 if cn is not None:
                     stats.chunks_counted_from_manifest += 1
                     stats.hits += cn
@@ -1223,31 +1242,34 @@ def _execute(src, query, stats: QueryStats, *, want_lines: bool = True,
                         yield (None, None)
                     continue
             try:
-                cr = open_fn()
+                with tm("query.decode"):
+                    cr = open_fn()
                 stats.chunks_opened += 1
-                tri_all = np.ones(cr.n, np.int8)
-                tris = []
-                for p in preds:
-                    t = _chunk_tri(p, ctx, cr, line_start, manifest)
-                    tris.append(t)
-                    np.minimum(tri_all, t, out=tri_all)
-                    if not (tri_all >= 0).any():
-                        break
+                with tm("query.filter"):
+                    tri_all = np.ones(cr.n, np.int8)
+                    tris = []
+                    for p in preds:
+                        t = _chunk_tri(p, ctx, cr, line_start, manifest)
+                        tris.append(t)
+                        np.minimum(tri_all, t, out=tri_all)
+                        if not (tri_all >= 0).any():
+                            break
                 hits = []
-                for pos in np.flatnonzero(tri_all >= 0).tolist():
-                    if tri_all[pos] == 1:
-                        if want_lines:
+                with tm("query.materialize"):
+                    for pos in np.flatnonzero(tri_all >= 0).tolist():
+                        if tri_all[pos] == 1:
+                            if want_lines:
+                                line = cr.line(pos)
+                                stats.rows_materialized += 1
+                            else:
+                                line = None
+                        else:
                             line = cr.line(pos)
                             stats.rows_materialized += 1
-                        else:
-                            line = None
-                    else:
-                        line = cr.line(pos)
-                        stats.rows_materialized += 1
-                        if not all(t[pos] == 1 or _test_line(p, line, line_start + pos)
-                                   for p, t in zip(preds, tris)):
-                            continue
-                    hits.append((line_start + pos, line))
+                            if not all(t[pos] == 1 or _test_line(p, line, line_start + pos)
+                                       for p, t in zip(preds, tris)):
+                                continue
+                        hits.append((line_start + pos, line))
             except ValueError:
                 if arch.salvage:
                     # damaged chunk in salvage mode: its lines are lost,
@@ -1395,24 +1417,27 @@ def extract_records(src, *, event: int | None = None,
     global line range. Verbatim lines are not template instances and are
     skipped."""
     st = stats if stats is not None else QueryStats()
-    arch = _ArchiveChunks(src, salvage=salvage)
+    tm = StageTimer(st.stage_times)
+    arch = _ArchiveChunks(src, salvage=salvage, tm=tm)
     try:
         for k, line_start, n_lines, manifest, open_fn, _screen in arch.chunks():
             st.chunks_total += 1
-            skip = False
-            if line_range is not None and n_lines is not None:
-                if not (line_start < line_range[1]
-                        and line_start + n_lines > line_range[0]):
-                    skip = True
-            if not skip and event is not None and manifest:
-                used = manifest.get("used")
-                if used is not None and event not in used:
-                    skip = True
+            with tm("query.screen"):
+                skip = False
+                if line_range is not None and n_lines is not None:
+                    if not (line_start < line_range[1]
+                            and line_start + n_lines > line_range[0]):
+                        skip = True
+                if not skip and event is not None and manifest:
+                    used = manifest.get("used")
+                    if used is not None and event not in used:
+                        skip = True
             if skip:
                 st.chunks_skipped += 1
                 continue
             try:
-                cr = open_fn()
+                with tm("query.decode"):
+                    cr = open_fn()
             except ValueError:
                 if arch.salvage:
                     st.chunks_skipped += 1
@@ -1421,30 +1446,31 @@ def extract_records(src, *, event: int | None = None,
             st.chunks_opened += 1
             if cr.level < 2:
                 continue
-            used = cr.used_global
-            events = cr.events
-            recs = []
-            for kk in (np.unique(events).tolist() if len(events) else []):
-                gid = used[kk] if used is not None else kk
-                if event is not None and gid != event:
-                    continue
-                tpl = cr.templates[kk]
-                tpl_str = " ".join("<*>" if t is None else t for t in tpl)
-                n_stars = sum(1 for t in tpl if t is None)
-                cols = [cr.star_column(kk, s) for s in range(n_stars)]
-                rows_m = cr.template_rows(kk)
-                positions = cr.ok_pos[cr.matched_rows[rows_m]]
-                for r, pos in enumerate(positions.tolist()):
-                    no = line_start + pos
-                    if line_range is not None and not (line_range[0] <= no < line_range[1]):
+            with tm("query.aggregate"):
+                used = cr.used_global
+                events = cr.events
+                recs = []
+                for kk in (np.unique(events).tolist() if len(events) else []):
+                    gid = used[kk] if used is not None else kk
+                    if event is not None and gid != event:
                         continue
-                    recs.append({
-                        "line": no,
-                        "event": gid,
-                        "template": tpl_str,
-                        "params": [u[iv[r]] for u, iv in cols],
-                    })
-            recs.sort(key=lambda rec: rec["line"])
+                    tpl = cr.templates[kk]
+                    tpl_str = " ".join("<*>" if t is None else t for t in tpl)
+                    n_stars = sum(1 for t in tpl if t is None)
+                    cols = [cr.star_column(kk, s) for s in range(n_stars)]
+                    rows_m = cr.template_rows(kk)
+                    positions = cr.ok_pos[cr.matched_rows[rows_m]]
+                    for r, pos in enumerate(positions.tolist()):
+                        no = line_start + pos
+                        if line_range is not None and not (line_range[0] <= no < line_range[1]):
+                            continue
+                        recs.append({
+                            "line": no,
+                            "event": gid,
+                            "template": tpl_str,
+                            "params": [u[iv[r]] for u, iv in cols],
+                        })
+                recs.sort(key=lambda rec: rec["line"])
             st.hits += len(recs)
             yield from recs
     finally:
@@ -1478,20 +1504,23 @@ def count_by_template(src, *, stats: QueryStats | None = None,
     and are excluded. Keys are session-global EventIDs for LZJS archives,
     chunk-local ids otherwise."""
     st = stats if stats is not None else QueryStats()
+    tm = StageTimer(st.stage_times)
     out: dict[int, int] = {}
-    arch = _ArchiveChunks(src, salvage=salvage)
+    arch = _ArchiveChunks(src, salvage=salvage, tm=tm)
     try:
         for k, line_start, n_lines, manifest, open_fn, _screen in arch.chunks():
             st.chunks_total += 1
             if manifest:
-                used, ec = manifest.get("used"), manifest.get("ec")
-                if used is not None and ec is not None and len(ec) == len(used):
-                    st.chunks_counted_from_manifest += 1
-                    for g, c in zip(used, ec):
-                        out[g] = out.get(g, 0) + c
-                    continue
+                with tm("query.screen"):
+                    used, ec = manifest.get("used"), manifest.get("ec")
+                    if used is not None and ec is not None and len(ec) == len(used):
+                        st.chunks_counted_from_manifest += 1
+                        for g, c in zip(used, ec):
+                            out[g] = out.get(g, 0) + c
+                        continue
             try:
-                cr = open_fn()
+                with tm("query.decode"):
+                    cr = open_fn()
             except ValueError:
                 if arch.salvage:
                     st.chunks_skipped += 1
@@ -1503,13 +1532,14 @@ def count_by_template(src, *, stats: QueryStats | None = None,
             # deferred: the manifest path must not pay the jax import
             from repro.kernels import ops as _kops
 
-            counts = _kops.distinct_counts(cr.events, len(cr.templates))
-            used = cr.used_global
-            for kk, c in enumerate(counts.tolist()):
-                if not c:
-                    continue
-                g = used[kk] if used is not None else kk
-                out[g] = out.get(g, 0) + c
+            with tm("query.aggregate"):
+                counts = _kops.distinct_counts(cr.events, len(cr.templates))
+                used = cr.used_global
+                for kk, c in enumerate(counts.tolist()):
+                    if not c:
+                        continue
+                    g = used[kk] if used is not None else kk
+                    out[g] = out.get(g, 0) + c
     finally:
         arch.close()
     st.hits = sum(out.values())
@@ -1531,49 +1561,53 @@ def top_k(src, field: str | None = None, *, event: int | None = None,
     from repro.kernels import ops as _kops
 
     st = stats if stats is not None else QueryStats()
+    tm = StageTimer(st.stage_times)
     totals: dict[str, int] = {}
-    arch = _ArchiveChunks(src, salvage=salvage)
+    arch = _ArchiveChunks(src, salvage=salvage, tm=tm)
     try:
         if field is not None:
             _validate_agg_field(arch, field)
         for kc, line_start, n_lines, manifest, open_fn, _screen in arch.chunks():
             st.chunks_total += 1
             if field is None and manifest:
-                used = manifest.get("used")
-                if used is not None and event not in used:
-                    st.chunks_skipped += 1
-                    continue
+                with tm("query.screen"):
+                    used = manifest.get("used")
+                    if used is not None and event not in used:
+                        st.chunks_skipped += 1
+                        continue
             try:
-                cr = open_fn()
+                with tm("query.decode"):
+                    cr = open_fn()
             except ValueError:
                 if arch.salvage:
                     st.chunks_skipped += 1
                     continue
                 raise
             st.chunks_opened += 1
-            if field is not None:
-                if not cr.n_ok:
+            with tm("query.aggregate"):
+                if field is not None:
+                    if not cr.n_ok:
+                        continue
+                    uniq, inv = cr.header_distinct(field)
+                else:
+                    if cr.level < 2 or not len(cr.events):
+                        continue
+                    used = cr.used_global
+                    kk = next((j for j in range(len(cr.templates))
+                               if (used[j] if used is not None else j) == event),
+                              None)
+                    if kk is None:
+                        continue
+                    n_stars = sum(1 for t in cr.templates[kk] if t is None)
+                    if star >= n_stars:
+                        continue  # no such column here: contributes nothing
+                    uniq, inv = cr.star_column(kk, star)
+                if not len(uniq):
                     continue
-                uniq, inv = cr.header_distinct(field)
-            else:
-                if cr.level < 2 or not len(cr.events):
-                    continue
-                used = cr.used_global
-                kk = next((j for j in range(len(cr.templates))
-                           if (used[j] if used is not None else j) == event),
-                          None)
-                if kk is None:
-                    continue
-                n_stars = sum(1 for t in cr.templates[kk] if t is None)
-                if star >= n_stars:
-                    continue  # no such column here: contributes nothing
-                uniq, inv = cr.star_column(kk, star)
-            if not len(uniq):
-                continue
-            counts = _kops.distinct_counts(inv, len(uniq))
-            for u, c in zip(uniq, counts.tolist()):
-                if c:
-                    totals[u] = totals.get(u, 0) + c
+                counts = _kops.distinct_counts(inv, len(uniq))
+                for u, c in zip(uniq, counts.tolist()):
+                    if c:
+                        totals[u] = totals.get(u, 0) + c
     finally:
         arch.close()
     st.hits = sum(totals.values())
@@ -1596,14 +1630,16 @@ def time_histogram(src, field: str, *, bucket: int = 60,
     from repro.kernels import ops as _kops
 
     st = stats if stats is not None else QueryStats()
+    tm = StageTimer(st.stage_times)
     out: dict[int, int] = {}
-    arch = _ArchiveChunks(src, salvage=salvage)
+    arch = _ArchiveChunks(src, salvage=salvage, tm=tm)
     try:
         _validate_agg_field(arch, field)
         for kc, line_start, n_lines, manifest, open_fn, _screen in arch.chunks():
             st.chunks_total += 1
             try:
-                cr = open_fn()
+                with tm("query.decode"):
+                    cr = open_fn()
             except ValueError:
                 if arch.salvage:
                     st.chunks_skipped += 1
@@ -1612,18 +1648,19 @@ def time_histogram(src, field: str, *, bucket: int = 60,
             st.chunks_opened += 1
             if not cr.n_ok:
                 continue
-            uniq, inv = cr.header_distinct(field)
-            if not len(uniq):
-                continue
-            counts = _kops.distinct_counts(inv, len(uniq))
-            for u, c in zip(uniq, counts.tolist()):
-                if not c:
+            with tm("query.aggregate"):
+                uniq, inv = cr.header_distinct(field)
+                if not len(uniq):
                     continue
-                m = _INT_CORE_RE.search(u)
-                if m is None:
-                    continue
-                b = int(m.group()) // bucket
-                out[b] = out.get(b, 0) + c
+                counts = _kops.distinct_counts(inv, len(uniq))
+                for u, c in zip(uniq, counts.tolist()):
+                    if not c:
+                        continue
+                    m = _INT_CORE_RE.search(u)
+                    if m is None:
+                        continue
+                    b = int(m.group()) // bucket
+                    out[b] = out.get(b, 0) + c
     finally:
         arch.close()
     st.hits = sum(out.values())

@@ -5,6 +5,12 @@ compiled, anywhere else (the CPU test suite) they run in Pallas
 interpret mode. Nothing needs setting either way; ``python chip_smoke.py``
 drives the main path on a chip and checks it end to end.
 
+Each main-path wrapper (``wildcard_match``, ``delta_zigzag``,
+``distinct_counts``) runs whole in a ``dispatch.<kernel>`` span of
+``repro.core.timing``: padding, transfer, launch, the device-side trim
+and the copy back, so a compile on the way (the trim's, per unpadded
+shape) lands as ``dispatch.<kernel>.compile`` in the caller's sink.
+
 ``wildcard_match_sharded`` is the pod-scale matcher: logs sharded over
 the mesh ``data`` axis, templates replicated — zero-collective data
 parallelism (the paper's "highly parallel matching" mapped onto a pod).
@@ -23,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import ref
 from repro.core.textops import first_occurrence_unique, runs_of
+from repro.core.timing import spanned
 
 from .colcodec import colcodec_transform as _colcodec_transform
 from .jitcache import bucket, bucket_stats, record_call, reset_counters  # noqa: F401 (re-exported)
@@ -242,6 +249,7 @@ def _pad_to(arr: np.ndarray, shape: tuple, fill=0) -> np.ndarray:
     return np.pad(arr, pads, constant_values=fill)
 
 
+@spanned("dispatch.wildcard_match")
 def wildcard_match(logs, lens, templates, t_lens, *, use_buckets: bool = True) -> jnp.ndarray:
     """-> (N, K) bool match matrix.
 
@@ -442,6 +450,7 @@ def match_extract(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]
 
 # ------------------------------------------ typed column codecs (device)
 
+@spanned("dispatch.colcodec_transform")
 def delta_zigzag(vals: np.ndarray, lens: np.ndarray, mode: np.ndarray,
                  *, use_buckets: bool = True) -> np.ndarray:
     """Batched typed-column transform (DESIGN.md §12): (R, C) int32
@@ -483,6 +492,7 @@ def delta_zigzag(vals: np.ndarray, lens: np.ndarray, mode: np.ndarray,
 
 # ----------------------------------------- compressed-domain scan (device)
 
+@spanned("dispatch.distinct_counts")
 def distinct_counts(inv, n_bins: int, weights=None, *,
                     prefer_host: bool | None = None) -> np.ndarray:
     """Weighted histogram of a distinct-row inverse index (DESIGN.md

@@ -224,7 +224,7 @@ def _cmd_grep(args) -> None:
             print(f"chunk {row['chunk']:4d} lines [{row['lines'][0]}:"
                   f"{row['lines'][1]})  {verdict}{probes}")
         return
-    stats = Q.QueryStats()
+    stats = Q.QueryStats(stage_times={} if args.stats else None)
     if args.count:
         print(Q.count(args.infile, q, stats=stats, salvage=args.salvage))
     else:
@@ -258,6 +258,10 @@ def _print_query_stats(stats) -> None:
     if stats.chunks_counted_from_manifest:
         print(f"query: {stats.chunks_counted_from_manifest} chunks counted "
               f"from their manifest histogram (never opened)", file=sys.stderr)
+    if stats.stage_times:
+        phases = ", ".join(f"{k.removeprefix('query.')} {v:.3f}"
+                           for k, v in stats.stage_times.items())
+        print(f"query: seconds by phase -> {phases}", file=sys.stderr)
 
 
 def _cmd_agg(args) -> None:
@@ -274,7 +278,7 @@ def _cmd_agg(args) -> None:
     if len(modes) != 1:
         sys.exit("agg wants exactly one of --by-template / --top / "
                  "--top-param / --histogram")
-    stats = Q.QueryStats()
+    stats = Q.QueryStats(stage_times={} if args.stats else None)
     mode = modes[0]
     if mode == "by_template":
         counts = Q.count_by_template(args.infile, stats=stats,
@@ -617,7 +621,8 @@ def main(argv: list[str] | None = None) -> None:
     g.add_argument("--json", action="store_true", help="JSON-lines output")
     g.add_argument("--limit", type=int, default=None, help="stop after N hits")
     g.add_argument("--stats", action="store_true",
-                   help="print chunks-decoded accounting to stderr")
+                   help="print chunks-decoded accounting and seconds by "
+                        "phase to stderr")
     g.add_argument("--explain", action="store_true",
                    help="print the per-template pushdown classification and exit")
     g.add_argument("--salvage", action="store_true",
@@ -639,7 +644,8 @@ def main(argv: list[str] | None = None) -> None:
     a.add_argument("-k", type=int, default=10, help="top-k size (default 10)")
     a.add_argument("--json", action="store_true", help="JSON-lines output")
     a.add_argument("--stats", action="store_true",
-                   help="print chunks-decoded accounting to stderr")
+                   help="print chunks-decoded accounting and seconds by "
+                        "phase to stderr")
     a.add_argument("--salvage", action="store_true",
                    help="aggregate a damaged LZJS container "
                         "(surviving chunks only)")

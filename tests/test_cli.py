@@ -181,3 +181,22 @@ def test_inspect_all_three_magics(log_file, tmp_path, monkeypatch, capsys):
     assert "LZJS stream: 1200 lines in 4 chunks" in out
     assert "session store:" in out
     assert "chunk   0" in out
+
+
+@pytest.mark.parametrize("argv,phases", [
+    (["grep", "{lzjs}", "rdd_7", "--stats"],
+     ("screen", "decode", "filter", "materialize")),
+    (["agg", "{lzjs}", "--top", "Level", "--stats"], ("decode", "aggregate")),
+])
+def test_query_stats_print_phase_seconds(log_file, tmp_path, monkeypatch, capsys, argv, phases):
+    lzjs = str(tmp_path / "a.lzjs")
+    _run(["stream", log_file, lzjs, "--format", FMT, "--chunk-lines", "300"],
+         monkeypatch, capsys)
+    monkeypatch.setattr(sys, "argv", ["compress"] + [a.format(lzjs=lzjs) for a in argv])
+    main()
+    err = capsys.readouterr().err
+    line = next(l for l in err.splitlines() if l.startswith("query: seconds by phase -> "))
+    got = dict(p.rsplit(" ", 1) for p in line.split(" -> ", 1)[1].split(", "))
+    assert set(phases) <= set(got)
+    assert all(float(v) >= 0 for v in got.values())
+    assert "query: " in err and " hits; decoded " in err  # the counts still print

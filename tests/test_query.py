@@ -3,6 +3,7 @@ grep on every container kind, template classification, chunk skipping via
 LZJS manifests, the param-dictionary screen, and the count/sample fast
 paths."""
 
+import dataclasses
 import io
 import json
 import re
@@ -289,3 +290,47 @@ def test_extract_records_roundtrip(archives, corpus):
             assert p in corpus[r["line"]]
     by_event = list(Q.extract_records(archives["lzjs"], event=recs[0]["event"]))
     assert all(r["event"] == recs[0]["event"] for r in by_event)
+
+
+# --------------------------------------------------------- phase timing
+
+PHASES = {"query.screen", "query.decode", "query.filter", "query.materialize",
+          "query.aggregate"}
+
+
+def _counts(st):
+    return {k: v for k, v in dataclasses.asdict(st).items() if k != "stage_times"}
+
+
+@pytest.mark.parametrize("kind", ["lzjf", "lzjm", "lzjs"])
+def test_stage_times_fill_the_query_phases(archives, kind):
+    times: dict = {}
+    timed = Q.QueryStats(stage_times=times)
+    hits = list(Q.search(archives[kind], Q.Substring("blk_"), stats=timed))
+    top = Q.top_k(archives[kind], "Level", k=3, stats=timed)
+    assert PHASES <= set(times)
+    assert all(v >= 0 for v in times.values())
+
+    plain = Q.QueryStats()
+    assert list(Q.search(archives[kind], Q.Substring("blk_"), stats=plain)) == hits
+    assert Q.top_k(archives[kind], "Level", k=3, stats=plain) == top
+    assert plain.stage_times is None
+    assert _counts(plain) == _counts(timed)
+
+
+@pytest.mark.parametrize("agg", [
+    lambda a, st: Q.count_by_template(a, stats=st),
+    lambda a, st: Q.time_histogram(a, "Time", bucket=100, stats=st),
+    lambda a, st: list(Q.extract_records(a, line_range=(100, 900), stats=st)),
+    lambda a, st: Q.top_k(a, event=0, star=0, stats=st),
+    lambda a, st: Q.count(a, Q.Substring("terminating"), stats=st),
+], ids=["by_template", "histogram", "extract", "top_param", "count"])
+def test_stage_times_leave_aggregations_unchanged(archives, agg):
+    for kind in ("lzjf", "lzjs"):
+        times: dict = {}
+        timed = Q.QueryStats(stage_times=times)
+        plain = Q.QueryStats()
+        assert agg(archives[kind], timed) == agg(archives[kind], plain)
+        assert times and set(times) <= PHASES | {"dispatch.distinct_counts"}
+        assert plain.stage_times is None
+        assert _counts(plain) == _counts(timed)
