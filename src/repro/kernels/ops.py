@@ -7,9 +7,16 @@ drives the main path on a chip and checks it end to end.
 
 Each main-path wrapper (``wildcard_match``, ``delta_zigzag``,
 ``distinct_counts``) runs whole in a ``dispatch.<kernel>`` span of
-``repro.core.timing``: padding, transfer, launch, the device-side trim
-and the copy back, so a compile on the way (the trim's, per unpadded
-shape) lands as ``dispatch.<kernel>.compile`` in the caller's sink.
+``repro.core.timing``: padding, transfer, launch, one copy of the whole
+padded result back to the host, and the trim to the unpadded shape in
+numpy. A compile on the way (a kernel's, for a bucket the process has
+not seen) lands as ``dispatch.<kernel>.compile`` in the caller's sink.
+
+Once the padded bucket is launched, no eager JAX op may run on an
+unpadded shape: slicing a ``jax.Array`` compiles a ``dynamic_slice``
+module per distinct output shape, and the unpadded shapes follow the
+data (one call per first-token group or per column), so no warm-up
+covers them. Bucketing only pays if the trim happens on the host.
 
 ``wildcard_match_sharded`` is the pod-scale matcher: logs sharded over
 the mesh ``data`` axis, templates replicated — zero-collective data
@@ -257,8 +264,10 @@ def wildcard_match(logs, lens, templates, t_lens, *, use_buckets: bool = True) -
     to a power-of-two bucket before hitting the jitted kernel, so
     streaming chunks with drifting shapes reuse one compiled executable
     per bucket (zero re-traces after warmup — ``jitcache.TRACE_COUNTS``
-    records the actual trace count). Padding is sliced/masked back out:
-    results are bit-identical to the unbucketed call.
+    records the actual trace count). The padded (nb, kb) result comes
+    back to the host whole and is trimmed and masked there, so a new
+    unpadded (N, K) inside a known bucket compiles nothing: results are
+    bit-identical to the unbucketed call.
     """
     logs = np.asarray(logs, np.int32)
     lens_np = np.asarray(lens, np.int32)
@@ -279,10 +288,10 @@ def wildcard_match(logs, lens, templates, t_lens, *, use_buckets: bool = True) -
             jnp.asarray(_pad_to(lens_np, (nb,))),
             jnp.asarray(_pad_to(templates, (kb, ttb))),
             jnp.asarray(np.pad(t_lens_np, (0, kb - k), constant_values=-1)),
-        )[:n, :k]
+        )
         # the padded width tb would let stars absorb PAD columns of lines
         # whose true length exceeds t: re-apply the host's truncation rule
-        return np.asarray(out).astype(bool) & (lens_np <= t)[:, None]
+        return np.asarray(out)[:n, :k].astype(bool) & (lens_np <= t)[:, None]
     out = _dispatch(
         "wildcard_match",
         jnp.asarray(logs), jnp.asarray(lens_np), jnp.asarray(templates),
@@ -418,8 +427,9 @@ def match_extract(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]
     matching template or -1, spans (N, n_slots, 2) int32).
 
     numpy in/out convenience over ``kernels.match_extract``; shapes are
-    bucketed like ``wildcard_match``. Over-length lines are masked here
-    (where the true width is known) rather than in the kernel.
+    bucketed like ``wildcard_match`` and the padded results trimmed on
+    the host. Over-length lines are masked here (where the true width is
+    known) rather than in the kernel.
     """
     ids = np.asarray(ids, np.int32)
     lens_np = np.asarray(lens, np.int32)
@@ -442,8 +452,8 @@ def match_extract(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]
         "match_extract",
         jnp.asarray(ids_p), jnp.asarray(lens_p), jnp.asarray(tmpl_p),
         jnp.asarray(tlens_p), n_slots=n_slots)
-    assign = np.asarray(assign[:n]).copy()
-    spans = np.asarray(spans[:n]).copy()
+    assign = np.asarray(assign)[:n].copy()
+    spans = np.asarray(spans)[:n].copy()
     assign[lens_np > t] = -1  # truncated lines never match (host rule)
     return assign, spans
 
@@ -461,7 +471,9 @@ def delta_zigzag(vals: np.ndarray, lens: np.ndarray, mode: np.ndarray,
     The frame-of-reference row minimum is computed here (over the valid
     prefix) and handed to the kernel as data. Shapes are bucketed to
     powers of two so the streaming encode path reuses one executable per
-    bucket; callers gate magnitudes with ``coltypes.KERNEL_SAFE``.
+    bucket; the padded (rb, cb) result comes back whole and is trimmed
+    on the host, so a column of a new length compiles nothing. Callers
+    gate magnitudes with ``coltypes.KERNEL_SAFE``.
     """
     vals = np.asarray(vals, np.int32)
     lens_np = np.asarray(lens, np.int32)
@@ -481,12 +493,12 @@ def delta_zigzag(vals: np.ndarray, lens: np.ndarray, mode: np.ndarray,
             jnp.asarray(_pad_to(lens_np, (rb,))),
             jnp.asarray(_pad_to(mode_np, (rb,))),
             jnp.asarray(_pad_to(ref, (rb,))),
-        )[:r, :width]
-    else:
-        out = _dispatch(
-            "colcodec_transform",
-            jnp.asarray(vals), jnp.asarray(lens_np), jnp.asarray(mode_np),
-            jnp.asarray(ref))
+        )
+        return np.asarray(out)[:r, :width]
+    out = _dispatch(
+        "colcodec_transform",
+        jnp.asarray(vals), jnp.asarray(lens_np), jnp.asarray(mode_np),
+        jnp.asarray(ref))
     return np.asarray(out)
 
 

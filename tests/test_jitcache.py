@@ -4,10 +4,30 @@ its shard_map'd callable once, and a 20-chunk kernel-path streaming
 session must be recompile-free after warmup."""
 
 import io
+from contextlib import contextmanager
 
+import jax
 import numpy as np
+import pytest
 
+from repro.core.timing import COMPILE_EVENT
 from repro.kernels import jitcache, ops
+
+
+@contextmanager
+def _backend_compiles():
+    """-> list that collects one entry per XLA backend compile in the block."""
+    seen: list[float] = []
+
+    def on_duration(event, duration, **_kw):
+        if event == COMPILE_EVENT:
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
 
 
 def _case(rng, n, t, k, tt):
@@ -66,6 +86,52 @@ def test_match_extract_trace_count_stable_within_bucket():
     assert jitcache.TRACE_COUNTS["match_extract"] == before, "re-traced within bucket"
 
 
+# each call helper returns the wrapper's results as a tuple of arrays
+
+def _wildcard_call(rng, n, k, use_buckets):
+    logs, lens, tmpl, tlens = _case(rng, n, 6, k, 4)
+    return (ops.wildcard_match(logs, lens, tmpl, tlens, use_buckets=use_buckets),)
+
+
+def _colcodec_call(rng, r, width, use_buckets):
+    vals = rng.integers(-500, 500, (r, width)).astype(np.int32)
+    lens = rng.integers(0, width + 1, r).astype(np.int32)
+    mode = rng.integers(1, 4, r).astype(np.int32)
+    return (ops.delta_zigzag(vals, lens, mode, use_buckets=use_buckets),)
+
+
+def _match_extract_call(rng, n, k, use_buckets):
+    logs, lens, tmpl, tlens = _case(rng, n, 6, k, 3)
+    # one trailing star each: equal n_slots across calls
+    tpls = [np.concatenate([tmpl[i, : tlens[i]], [1]]).astype(np.int32)
+            for i in range(k)]
+    return ops.match_extract(logs, lens, tpls, use_buckets=use_buckets)
+
+
+@pytest.mark.parametrize("call, shapes", [
+    # every shape of a case shares one bucket: wildcard_match (N 256, K 16),
+    # delta_zigzag (R 8, C 128), match_extract (N 64, K 16)
+    (_wildcard_call, [(100, 3), (37, 9), (250, 16), (5, 1)]),
+    (_colcodec_call, [(1, 40), (3, 97), (8, 128), (2, 5)]),
+    (_match_extract_call, [(40, 3), (17, 5), (64, 2), (3, 1)]),
+], ids=["wildcard_match", "delta_zigzag", "match_extract"])
+def test_unpadded_shapes_within_bucket_compile_nothing(call, shapes):
+    """A new unpadded shape inside a warm bucket costs no XLA compile:
+    the wrappers trim the padded result on the host, never with an eager
+    device slice (one ``dynamic_slice`` module per unpadded shape)."""
+    rng = np.random.default_rng(5)
+    call(rng, *shapes[0], True)                  # warm the bucket
+    for shape in shapes[1:]:
+        seed = int(rng.integers(1 << 30))
+        with _backend_compiles() as compiles:
+            got = call(np.random.default_rng(seed), *shape, True)
+        assert compiles == [], (shape, len(compiles))
+        want = call(np.random.default_rng(seed), *shape, False)
+        for g, w in zip(got, want, strict=True):
+            assert g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+
+
 def test_tokenizer_trace_count_stable_across_batch_sizes():
     # pack_lines buckets the ROW axis on the host: drifting batch sizes
     # must hit one compiled tokenizer executable per (rows, width) bucket
@@ -92,8 +158,8 @@ def test_sharded_matcher_traces_once():
 
 
 def test_streaming_session_zero_recompiles_after_warmup():
-    """ISSUE 3 acceptance: 20-chunk kernel-path session, zero re-traces
-    after the warmup chunks."""
+    """20-chunk kernel-path session: zero re-traces and zero XLA backend
+    compiles after the warmup chunks."""
     from repro.core.codec import LogzipConfig
     from repro.core.ise import ISEConfig
     from repro.core.stream import LZJSReader, StreamingCompressor
@@ -105,15 +171,20 @@ def test_streaming_session_zero_recompiles_after_warmup():
         ise=ISEConfig(min_sample=120, max_iters=2, use_kernel=True))
     buf = io.BytesIO()
     traces_after_warmup = None
+    compiles_per_chunk = []
     with StreamingCompressor(buf, cfg, chunk_lines=200, pipeline=False) as sc:
         for k in range(20):
-            sc.feed(lines[k * 200:(k + 1) * 200])
-            sc.flush_chunk()
+            with _backend_compiles() as compiles:
+                sc.feed(lines[k * 200:(k + 1) * 200])
+                sc.flush_chunk()
+            compiles_per_chunk.append(len(compiles))
             if k == 1:  # warmup = first two chunks (store still growing)
                 traces_after_warmup = dict(jitcache.TRACE_COUNTS)
     assert dict(jitcache.TRACE_COUNTS) == traces_after_warmup, (
         "kernel re-traced after warmup", traces_after_warmup,
         dict(jitcache.TRACE_COUNTS))
+    assert sum(compiles_per_chunk[2:]) == 0, (
+        "XLA compiled after warmup", compiles_per_chunk)
     assert LZJSReader(io.BytesIO(buf.getvalue())).read_all() == lines
 
 
