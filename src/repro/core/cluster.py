@@ -19,6 +19,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .lcs import common_token_count, lcs_merge
+from .match import NARROW_TOKENS
 from .tokenizer import PAD_ID, STAR_ID
 
 
@@ -67,11 +68,16 @@ def coarse_groups(
     cfg: ClusterConfig,
     vocab_size: int,
 ) -> np.ndarray:
-    """-> group id per line (N,), grouping by (level, component, top-k)."""
+    """-> group id per line (N,), grouping by (level, component, top-k),
+    and a multi-line record (wider than ``NARROW_TOKENS``) by its token
+    count too: its lines repeat the same frames, so its top-k tokens are
+    those of every stack trace and say nothing of which trace it is."""
     n = ids.shape[0]
+    lens = np.asarray(lens, np.int64)
     cols = [
         levels.astype(np.int64) if levels is not None else np.zeros(n, np.int64),
         comps.astype(np.int64) if comps is not None else np.zeros(n, np.int64),
+        np.where(lens > NARROW_TOKENS, lens, 0)[:, None],
         top_frequent_tokens(ids, lens, cfg.n_top_tokens, vocab_size),
     ]
     keys = np.column_stack(cols)
@@ -112,6 +118,38 @@ def fine_cluster_group(ids: np.ndarray, lens: np.ndarray, cfg: ClusterConfig) ->
     return templates
 
 
+def align_cluster_group(ids: np.ndarray, cfg: ClusterConfig) -> list[np.ndarray]:
+    """Fine-grained clustering of one coarse group of multi-line records,
+    which all have the same token count L (``coarse_groups`` keys them on
+    it): the streaming pass of ``fine_cluster_group`` with positional
+    agreement in place of LCS, O(L) a pair instead of O(L^2). A record
+    joins the cluster whose kept positions it agrees with most, if more
+    than theta of its tokens; the positions where it disagrees are no
+    longer kept. A cluster's template is its first record with each run
+    of positions not kept as one '*'."""
+    n, width = ids.shape
+    theta = cfg.theta_ratio * width
+    reps = np.zeros((0, width), ids.dtype)
+    keep = np.zeros((0, width), bool)
+    for r in range(n):
+        row = ids[r]
+        if len(reps):
+            agree = (reps == row) & keep
+            phi = agree.sum(axis=1)
+            best = int(np.argmax(phi))
+            if phi[best] > theta:
+                keep[best] = agree[best]
+                continue
+        if len(reps) < cfg.max_clusters_per_group:
+            reps = np.vstack([reps, row[None]])
+            keep = np.vstack([keep, np.ones((1, width), bool)])
+    out = []
+    for rep, k in zip(reps, keep):
+        tpl = np.where(k, rep, STAR_ID).astype(np.int32)
+        out.append(tpl[k | np.concatenate([[True], k[:-1]])])  # a run of stars is one
+    return out
+
+
 def cluster_sample(
     ids: np.ndarray,
     lens: np.ndarray,
@@ -126,7 +164,10 @@ def cluster_sample(
     seen: set[tuple] = set()
     for g in np.unique(groups):
         sel = groups == g
-        for tpl in fine_cluster_group(ids[sel], lens[sel], cfg):
+        n_tok = int(lens[sel][0])  # one count in a group of multi-line records
+        fine = (align_cluster_group(ids[sel, :n_tok], cfg) if n_tok > NARROW_TOKENS
+                else fine_cluster_group(ids[sel], lens[sel], cfg))
+        for tpl in fine:
             key = tuple(int(x) for x in tpl)
             if key not in seen:
                 seen.add(key)

@@ -260,8 +260,9 @@ class ChunkReader:
             if self.fmt is None or field not in self.fmt.fields or \
                     field == self.fmt.content_field:
                 raise ValueError(f"no header field {field!r} in this archive")
-            col = ColumnCodec(f"h.{field}").decode_distinct(
-                self.objects, self.n_ok, self.paravalues)
+            # header columns are encoded without the ParamDict (their
+            # sub-field parts are text at every level), as header_column reads them
+            col = ColumnCodec(f"h.{field}").decode_distinct(self.objects, self.n_ok)
             self._header_distinct[field] = col
         return col
 

@@ -116,6 +116,12 @@ _INT64_SAFE = 1 << 62
 # the Pallas kernel works in int32 lanes: second differences of values
 # below this bound cannot overflow (|dod| <= 4 * 2**28 < 2**31)
 KERNEL_SAFE = 1 << 28
+# the platform's default sends a column this long or longer to the
+# kernel: a launch costs about a millisecond of host work around it (PERF
+# section 5), more than numpy takes for a column shorter than the kernel's
+# narrowest column bucket (a stack trace's template has a few such star
+# columns a chunk)
+KERNEL_MIN_VALUES = 128
 
 # mini-dict admission: enough rows to amortize the dict, and few enough
 # distinct values that indices stay ~1 byte
@@ -388,12 +394,13 @@ def _transform_numpy(arr: np.ndarray, kind: int) -> np.ndarray:
 
 def _transformed_stream(vals: list[int], kind: int,
                         use_kernel: bool | None) -> list | np.ndarray:
-    """``use_kernel=None`` takes the Pallas kernel on a TPU backend."""
+    """``use_kernel=None`` takes the Pallas kernel on a TPU backend for a
+    column of at least ``KERNEL_MIN_VALUES`` values."""
     hi = max(abs(min(vals)), abs(max(vals)))
     if use_kernel is not False and hi < KERNEL_SAFE:
         from repro.kernels.ops import delta_zigzag, on_tpu
 
-        if use_kernel or on_tpu():
+        if use_kernel or (on_tpu() and len(vals) >= KERNEL_MIN_VALUES):
             return delta_zigzag(np.asarray([vals], np.int32),
                                 np.asarray([len(vals)], np.int32),
                                 np.asarray([kind], np.int32))[0, :len(vals)].astype(np.int64)

@@ -16,7 +16,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from .cluster import ClusterConfig, cluster_sample
-from .match import match_first
+from .match import NARROW_TOKENS, match_first
 from .timing import StageTimer
 
 
@@ -54,10 +54,40 @@ def iterative_structure_extraction(
     cfg: ISEConfig | None = None,
     stage_times: dict | None = None,
 ) -> ISEResult:
+    """ISE over ``ids`` (N, W). Rows longer than W were truncated (over
+    the token budget): they are never clustered and match nothing. Rows
+    of at most ``NARROW_TOKENS`` tokens and the wider ones (multi-line
+    records) are extracted apart, each at its own width, so that a few
+    long records neither widen nor reshape the clustering of the rest."""
     cfg = cfg or ISEConfig()
+    vocab_size = vocab_size or int(ids.max(initial=1)) + 1
+    n, width = ids.shape
+    lens = np.asarray(lens)
+    if width <= NARROW_TOKENS and (lens <= width).all():
+        return _ise(ids, lens, levels, comps, vocab_size, cfg, stage_times)
+    assign = np.full((n,), -1, np.int32)
+    templates: list[np.ndarray] = []
+    rates: list[float] = []
+    sampled: list[int] = []
+    for rows in (np.flatnonzero(lens <= min(width, NARROW_TOKENS)),
+                 np.flatnonzero((lens > NARROW_TOKENS) & (lens <= width))):
+        if not len(rows):
+            continue
+        w = max(1, int(lens[rows].max()))
+        res = _ise(ids[rows, :w], lens[rows],
+                   levels[rows] if levels is not None else None,
+                   comps[rows] if comps is not None else None,
+                   vocab_size, cfg, stage_times)
+        assign[rows] = np.where(res.assign >= 0, res.assign + len(templates), -1)
+        templates.extend(res.templates)
+        rates.extend(res.match_rate_per_iter)
+        sampled.extend(res.sampled_per_iter)
+    return ISEResult(templates, assign, rates, sampled)
+
+
+def _ise(ids, lens, levels, comps, vocab_size, cfg: ISEConfig, stage_times) -> ISEResult:
     tm = StageTimer(stage_times)
     n = ids.shape[0]
-    vocab_size = vocab_size or int(ids.max(initial=1)) + 1
     rng = np.random.default_rng(cfg.seed)
 
     assign = np.full((n,), -1, np.int32)

@@ -35,16 +35,11 @@ def lcs_merge(a: np.ndarray, b: np.ndarray) -> np.ndarray:
             dp[i] = np.maximum(dp[i - 1], dp[i])
             dp[i] = np.maximum.accumulate(dp[i])
             continue
-        match = (b == ai).astype(np.int32)
-        # vectorized row update: dp[i][j] = max(dp[i-1][j], dp[i][j-1],
-        #                                       dp[i-1][j-1] + match)
+        # dp[i][j] = max(dp[i-1][j], dp[i][j-1], dp[i-1][j-1] + match):
+        # the dp[i][j-1] term is a running max along the row
         row_prev = dp[i - 1]
-        row = dp[i]
-        best = 0
-        for j in range(1, m + 1):
-            cand = row_prev[j - 1] + match[j - 1] if match[j - 1] else 0
-            best = max(row_prev[j], best, cand)
-            row[j] = best
+        cand = np.where(b == ai, row_prev[:-1] + 1, 0)
+        np.maximum.accumulate(np.maximum(row_prev[1:], cand), out=dp[i, 1:])
     # backtrack
     out: list[int] = []
     i, j = n, m

@@ -30,16 +30,44 @@ root-level pruning) cuts the candidate template set per line, and exact
 duplicate (ids, len) rows are collapsed before the DP runs — matching is
 deterministic per row, so the result is identical, but real logs are
 dominated by repeats and only pay for distinct lines.
+
+Rows are matched at the width of their own token bucket (``width_groups``):
+one multi-line record of 400 tokens must not make every one-line record
+of its chunk pay for a 512-token DP. Rows wider than ``NARROW_TOKENS``
+run in the ``ise.match.wide`` span.
 """
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
+from .timing import span
 from .tokenizer import STAR_ID
 
 CHUNK = 4096  # lines per DP chunk (bounds the M tensor)
 DEDUP_MIN_LINES = 512  # below this the np.unique sort costs more than it saves
+NARROW_TOKENS = 128  # the narrowest token bucket: one-line records fit it
+
+
+def width_groups(lens: np.ndarray, width: int) -> list[tuple[int, np.ndarray]]:
+    """Rows by token bucket: ``[(w, rows), ...]`` with ``w`` the tight
+    width of ``rows`` (the longest of them), for buckets of
+    ``NARROW_TOKENS``, then doubling. Rows longer than ``width`` were
+    truncated (over the token budget): they match nothing and are in
+    no group."""
+    lens = np.asarray(lens, np.int64)
+    fit = lens <= width
+    # 0 up to NARROW_TOKENS tokens, then 1, 2, ... per doubling
+    over = np.maximum(lens, NARROW_TOKENS + 1) - 1
+    bucket = np.where(lens <= NARROW_TOKENS, 0,
+                      np.floor(np.log2(over)).astype(np.int64) - (NARROW_TOKENS.bit_length() - 2))
+    out = []
+    for b in np.unique(bucket[fit]).tolist():
+        rows = np.flatnonzero(fit & (bucket == b))
+        out.append((max(1, int(lens[rows].max())), rows))
+    return out
 
 
 def _dp_columns(ids: np.ndarray, lens: np.ndarray, template: np.ndarray) -> np.ndarray:
@@ -149,6 +177,12 @@ def _occ_ends(ids: np.ndarray, lit: np.ndarray) -> np.ndarray:
         return occ
     if L > t:
         return occ
+    if L > 16 and n * (t - L + 1) * L <= 1 << 22:
+        # a long run (a stack trace's frames): one compare over the
+        # (N, T-L+1, L) window view instead of L Python-level passes
+        win = np.lib.stride_tricks.sliding_window_view(ids, L, axis=1)
+        occ[:, L:] = (win == np.asarray(lit, ids.dtype)).all(axis=2)
+        return occ
     acc = ids[:, :t - L + 1] == int(lit[0])
     for k in range(1, L):
         acc = acc & (ids[:, k:t - L + 1 + k] == int(lit[k]))
@@ -243,13 +277,32 @@ def match_first(
     With ``dedup`` (default) duplicate (ids, len) rows are matched once
     and the assignment is broadcast back — bit-identical results, and the
     DP only pays for distinct lines. ``use_kernel=None`` runs the Pallas
-    kernel path on a TPU backend and the numpy path elsewhere.
+    kernel path on a TPU backend and the numpy path elsewhere. Wider
+    than ``NARROW_TOKENS``, each token bucket (``width_groups``) is
+    matched at its own width against the templates that fit it (a
+    template of m units needs at least m tokens).
     """
     n = ids.shape[0]
-    assign = np.full((n,), -1, np.int32)
     if not templates or n == 0:
-        return assign
+        return np.full((n,), -1, np.int32)
+    if ids.shape[1] <= NARROW_TOKENS:
+        return _match_first(ids, lens, templates, use_kernel, dedup)
+    assign = np.full((n,), -1, np.int32)
+    t_len = np.array([len(t) for t in templates])
+    for w, rows in width_groups(lens, ids.shape[1]):
+        fit = np.flatnonzero(t_len <= w)
+        if not len(fit):
+            continue
+        with span("ise.match.wide") if w > NARROW_TOKENS else contextlib.nullcontext():
+            a = _match_first(ids[rows, :w], lens[rows], [templates[k] for k in fit],
+                             use_kernel, dedup)
+        assign[rows] = np.where(a >= 0, fit[np.maximum(a, 0)], -1)
+    return assign
 
+
+def _match_first(ids, lens, templates, use_kernel, dedup) -> np.ndarray:
+    n = ids.shape[0]
+    assign = np.full((n,), -1, np.int32)
     if dedup and n >= DEDUP_MIN_LINES:
         # memcmp-sort on a void view of the packed rows — much cheaper
         # than np.unique(axis=0)'s per-column lexsort; only the grouping
@@ -258,10 +311,7 @@ def match_first(
         rows = key.view(np.dtype((np.void, key.shape[1] * key.itemsize))).ravel()
         _, first, inv = np.unique(rows, return_index=True, return_inverse=True)
         if len(first) < n:
-            sub = match_first(
-                ids[first], lens[first], templates,
-                use_kernel=use_kernel, dedup=False,
-            )
+            sub = _match_first(ids[first], lens[first], templates, use_kernel, False)
             return sub[inv].astype(np.int32)
 
     if use_kernel is not False:

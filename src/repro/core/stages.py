@@ -64,7 +64,9 @@ class LogzipConfig:
     level: int = 3                  # 1 | 2 | 3 (paper's levels)
     kernel: str = "gzip"
     format: str | None = None       # loghub format string, None = content-only
-    max_tokens: int = 128
+    # token budget of a record's content: wider records go verbatim; a
+    # multi-line record (a 128-frame stack trace) is about 400 tokens
+    max_tokens: int = 512
     ise: ISEConfig = dfield(default_factory=ISEConfig)
     # paper §III-E: a pre-extracted TemplateStore skips ISE — new logs are
     # matched against the stored templates (stable EventIDs across archives)
@@ -147,10 +149,9 @@ class Chunk:
     uniq: list[str] | None = None
     grid: TokenGrid | None = None            # batched tokens/delims/offsets
     vocab: Vocab | None = None
-    ids_u: np.ndarray | None = None
+    ids_u: np.ndarray | None = None          # per distinct content
     lens_u: np.ndarray | None = None
-    ids: np.ndarray | None = None
-    lens: np.ndarray | None = None
+    lens: np.ndarray | None = None           # per line
     levels: np.ndarray | None = None
     comps: np.ndarray | None = None
     # -- structure_stage
@@ -214,7 +215,6 @@ def dedup_stage(ch: Chunk, cfg: LogzipConfig, tm: StageTimer) -> None:
         ch.grid = tokenize_batch(ch.uniq, ch.vocab, cfg.max_tokens, tight=True)
     with tm("encode"):
         ch.ids_u, ch.lens_u = ch.grid.ids, ch.grid.lens
-        ch.ids = ch.ids_u[ch.inverse]
         ch.lens = ch.lens_u[ch.inverse]
         ch.levels = factorize(ch.columns["Level"])[0] if "Level" in ch.columns else None
         ch.comps = factorize(ch.columns["Component"])[0] if "Component" in ch.columns else None
@@ -235,11 +235,12 @@ def structure_stage(ch: Chunk, cfg: LogzipConfig, tm: StageTimer,
     if cfg.template_store is not None:
         tpl_ids = cfg.template_store.to_id_arrays(ch.vocab)
         with tm("ise.match"):
-            a = match_first(ch.ids, ch.lens, tpl_ids, use_kernel=cfg.ise.use_kernel)
+            a = match_first(ch.ids_u, ch.lens_u, tpl_ids,
+                            use_kernel=cfg.ise.use_kernel)[ch.inverse]
         res = ISEResult(tpl_ids, a, [float((a >= 0).mean())], [])
         ch.meta["template_store"] = True
     else:
-        res = iterative_structure_extraction(ch.ids, ch.lens, ch.levels, ch.comps,
+        res = iterative_structure_extraction(ch.ids_u[ch.inverse], ch.lens, ch.levels, ch.comps,
                                              len(ch.vocab), cfg.ise, stage_times=tm.sink)
     ch.templates = res.templates
     ch.match_rate = res.match_rate
@@ -250,17 +251,18 @@ def structure_stage(ch: Chunk, cfg: LogzipConfig, tm: StageTimer,
 def _structure_session(ch: Chunk, cfg: LogzipConfig, tm: StageTimer, store) -> None:
     ch.session = True
     ch.tpl_base = len(store)
-    n = ch.ids.shape[0]
+    n = len(ch.inverse)
     assign = np.full((n,), -1, np.int64)
     if len(store):
+        # matching is a function of the row: match each distinct content once
         with tm("ise.match"):
-            a = match_first(ch.ids, ch.lens, store.to_id_arrays(ch.vocab),
+            a = match_first(ch.ids_u, ch.lens_u, store.to_id_arrays(ch.vocab),
                             use_kernel=cfg.ise.use_kernel)
-        assign = a.astype(np.int64)
+        assign = a[ch.inverse].astype(np.int64)
     rem = np.nonzero(assign < 0)[0]
     if rem.size:
         res = iterative_structure_extraction(
-            ch.ids[rem], ch.lens[rem],
+            ch.ids_u[ch.inverse[rem]], ch.lens[rem],
             ch.levels[rem] if ch.levels is not None else None,
             ch.comps[rem] if ch.comps is not None else None,
             len(ch.vocab), cfg.ise, stage_times=tm.sink)
@@ -449,7 +451,10 @@ def _template_params(tpl, line_idx, inverse, grid: TokenGrid, vocab_arr):
     u_lines = inverse[line_idx]
     uu_inv, ufirst = first_occurrence_unique(u_lines)
     uu_arr = u_lines[ufirst]  # uniques in first-line-occurrence order
-    spans_u = extract_spans(grid.ids[uu_arr], grid.lens[uu_arr], tpl)
+    # at the rows' own width: a chunk's widest record must not widen the
+    # anchor pass of every template
+    width = max(1, int(grid.lens[uu_arr].max(initial=1)))
+    spans_u = extract_spans(grid.ids[uu_arr, :width], grid.lens[uu_arr], tpl)
     n_uu, n_stars = spans_u.shape[:2]
     widths = spans_u[:, :, 1] - spans_u[:, :, 0]
 
@@ -467,7 +472,7 @@ def _template_params(tpl, line_idx, inverse, grid: TokenGrid, vocab_arr):
     # one class share every delimiter run and every star width, so the
     # walk below runs once per class, not once per unique line
     tpl_is_star = [int(t) == STAR_ID for t in tpl]
-    dl = grid.delim_ids[uu_arr]
+    dl = grid.delim_ids[uu_arr, :width + 1]
     gkey = np.ascontiguousarray(np.concatenate([widths.astype(np.int32), dl], axis=1))
     rows_v = gkey.view(np.dtype((np.void, gkey.shape[1] * gkey.itemsize))).ravel()
     ginv, gfirst = first_occurrence_unique(rows_v)

@@ -53,6 +53,7 @@ from . import integrity
 from .codec import _decompress_objects, open_container, read_structured
 from .encode import ParamDict, join_column, split_column, write_varint
 from .integrity import CRC_LEN, IntegrityError
+from .match import NARROW_TOKENS
 from .screens import OPT_MAGIC, SCREEN_KIND, ScreenBuilder, parse_screen_payload
 from .stages import (
     LogzipConfig,
@@ -468,6 +469,11 @@ class StreamingCompressor:
         self._pending: list = []    # in-flight pack/write futures
         self._buf: list[str] = []
         self._buf_bytes = 0
+        # records of this session stored verbatim (header-parse failures,
+        # unmatched or over the token budget), and records wider than
+        # the narrowest token bucket that rode the template path
+        self.verbatim_records = 0
+        self.wide_records_templated = 0
         self._closed = False
         self._summary: dict | None = None
         self._append = bool(append)
@@ -585,6 +591,8 @@ class StreamingCompressor:
             "buffered_lines": len(self._buf),
             "n_templates": len(self.session.store.templates),
             "n_params": len(self.session.paradict.values),
+            "verbatim_records": self.verbatim_records,
+            "wide_records_templated": self.wide_records_templated,
         }
 
     @property
@@ -642,6 +650,11 @@ class StreamingCompressor:
             return
         ch = run_stages(self._buf, self.cfg, stage_times=self.stage_times,
                         session=self.session)
+        self.verbatim_records += len(ch.bad_idx)
+        if ch.assign is not None:
+            self.verbatim_records += int((ch.assign < 0).sum())
+            self.wide_records_templated += int(
+                ((ch.assign >= 0) & (ch.lens > NARROW_TOKENS)).sum())
         n_chunk_lines = len(self._buf)
         line_start = self.total_lines
         self.total_lines += n_chunk_lines
@@ -846,6 +859,8 @@ class StreamingCompressor:
             "n_lines": self.total_lines, "n_chunks": len(self.index),
             "n_templates": len(self.session.store.templates),
             "n_params": len(self.session.paradict.values),
+            "verbatim_records": self.verbatim_records,
+            "wide_records_templated": self.wide_records_templated,
         }
         return self._summary
 

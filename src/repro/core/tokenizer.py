@@ -5,9 +5,14 @@ A ``LogFormat`` turns a loghub-style format string, e.g.::
     "<Date> <Time> <Level> <Component>: <Content>"
 
 into a compiled regex with named groups (same convention as logparser /
-the original logzip). ``parse`` splits every raw line into header-field
-columns plus the free-text message content; lines that do not match the
+the original logzip). ``parse`` splits every record into header-field
+columns plus the free-text message content; records that do not match the
 format are routed to a verbatim side-channel so compression stays lossless.
+
+A record is a header line plus the lines after it that do not parse as a
+header (a log4j stack trace, say), joined by ``"\\n"``: the header comes
+from its first line and ``Content`` carries the rest. ``LogFormat.records``
+assembles records from physical lines.
 
 ``tokenize`` splits message content into (tokens, delimiters) where the
 delimiter strings are preserved exactly: ``reassemble(tokens, delims)``
@@ -33,9 +38,10 @@ STAR_ID = 1
 _N_RESERVED = 2
 
 # Token delimiters used by the paper's implementation: whitespace plus a
-# small set of punctuation. A "token" is a maximal run of non-delimiter
+# small set of punctuation, and the newline that separates the lines of a
+# multi-line record. A "token" is a maximal run of non-delimiter
 # characters; delimiter runs are preserved verbatim.
-DEFAULT_DELIMITERS = " \t,;:="
+DEFAULT_DELIMITERS = " \t,;:=\n"
 _TOKEN_RE_CACHE: dict[str, re.Pattern] = {}
 
 
@@ -136,9 +142,9 @@ def tokenize_batch(
     id assignment order, and tokens/delims reconstruct ``tokenize``'s
     output exactly.
 
-    Contents are joined with ``\\n`` (never a token or delimiter char);
-    a content containing a newline — or one that defeats utf-8 encoding
-    — routes the whole batch through the scalar reference path.
+    Contents are joined with ``\\x00`` (a delimiter of the batch only); a
+    content containing a NUL — or one that defeats utf-8 encoding —
+    routes the whole batch through the scalar reference path.
     """
     n = len(contents)
     if n == 0:
@@ -147,21 +153,21 @@ def tokenize_batch(
                          np.zeros(0, np.int64), np.zeros(0, np.int64),
                          np.zeros(1, np.int64))
     try:
-        if any("\n" in c for c in contents):
+        if any("\x00" in c for c in contents):
             raise ValueError
-        data = "\n".join(contents).encode("utf-8", "surrogateescape")
+        data = "\x00".join(contents).encode("utf-8", "surrogateescape")
     except (ValueError, UnicodeEncodeError):
         return _tokenize_batch_reference(contents, vocab, max_len,
                                          delimiters=delimiters, tight=tight)
     buf = np.frombuffer(data, np.uint8)
     lut = _DELIM_LUT_CACHE.get(delimiters)
     if lut is None:
-        lut = class_mask(delimiters + "\n")
+        lut = class_mask(delimiters + "\x00")
         _DELIM_LUT_CACHE[delimiters] = lut
     tok_mask = ~lut[buf]
 
     starts, ends = runs_of(tok_mask)
-    line_starts = np.concatenate([[0], np.flatnonzero(buf == 0x0A) + 1])
+    line_starts = np.concatenate([[0], np.flatnonzero(buf == 0) + 1])
     line_ends = np.concatenate([line_starts[1:] - 1, [len(buf)]])
     line_of = np.searchsorted(line_starts, starts, side="right") - 1
     lens = np.bincount(line_of, minlength=n).astype(np.int32)
@@ -304,12 +310,19 @@ class LogFormat:
                     break
             else:
                 self._fast_cores = cores
+        # a record's first line holds the header; the lines after it ride
+        # in the content, which must then close the format
+        self._multiline = (self.fields[-1] == self.content_field
+                           and self._segments[-1] == "")
 
     def parse(self, lines: list[str], *, fast: bool = True) -> tuple[dict[str, list[str]], list[int], list[int]]:
-        """Parse lines -> (field columns, matched line idx, unmatched line idx).
+        """Parse records -> (field columns, matched idx, unmatched idx).
 
+        The header is read from a record's first line; with the content
+        field last, the record's further lines (after its first
+        ``"\\n"``) are the rest of ``Content``.
         To keep the header losslessly reconstructible even with irregular
-        whitespace, a matched line must round-trip through ``render``;
+        whitespace, a matched record must round-trip through ``render``;
         otherwise it is treated as unmatched (stored verbatim).
         ``fast=False`` forces the regex reference path (oracle for the
         split fast path, which is property-tested to agree).
@@ -330,7 +343,10 @@ class LogFormat:
         return dict(zip(self.fields, cols)), ok_idx, bad_idx
 
     def _parse_regex_line(self, line: str) -> tuple | None:
-        m = self.regex.match(line)
+        first, nl, rest = line.partition("\n")
+        if nl and not self._multiline:
+            return None
+        m = self.regex.match(first)
         if m is None:
             return None
         vals = m.groups()  # named groups appear in field order
@@ -338,7 +354,29 @@ class LogFormat:
         rendered = segs[0]
         for v, seg in zip(vals, segs[1:]):
             rendered += v + seg
-        return vals if rendered == line else None
+        if rendered != first:
+            return None
+        return vals[:-1] + (vals[-1] + nl + rest,) if nl else vals
+
+    def is_header(self, line: str) -> bool:
+        """Whether ``line`` (one physical line) opens a record."""
+        return self._parse_regex_line(line) is not None
+
+    def records(self, lines):
+        """Group physical lines into records: a line that parses as a
+        header opens one, and every following line that does not is
+        joined to it with ``"\\n"``. Lines before the first header form
+        one record of their own (which parses as no header, so it is
+        stored verbatim). ``"\\n".join`` of the records is
+        ``"\\n".join`` of the lines."""
+        cur: list[str] = []
+        for line in lines:
+            if cur and self.is_header(line):
+                yield "\n".join(cur)
+                cur = []
+            cur.append(line)
+        if cur:
+            yield "\n".join(cur)
 
     def _parse_fast(self, lines: list[str]) -> tuple[dict[str, list[str]], list[int], list[int]]:
         """One ``str.split`` per line for regular lines; regex fallback
@@ -357,7 +395,7 @@ class LogFormat:
         bad_idx: list[int] = []
         for i, line in enumerate(lines):
             parts = line.split(" ", nsep)
-            ok = len(parts) == nsep + 1 and "\n" not in parts[nsep]
+            ok = len(parts) == nsep + 1
             if ok:
                 for j in range(nsep):
                     p = parts[j]

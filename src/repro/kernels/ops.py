@@ -281,6 +281,11 @@ def wildcard_match(logs, lens, templates, t_lens, *, use_buckets: bool = True) -
         # power of two) so warm sessions never leave their bucket
         nb, tb = bucket(n, 256), bucket(t, 32)
         kb, ttb = bucket(k, 16), bucket(tt, 16)
+        if tb > 128:
+            # multi-line records: one shape per token bucket, so that a
+            # session's warm-up compiles it (the kernel stops at each
+            # template tile's longest template: the padding adds no steps)
+            kb, ttb = bucket(k, 32), max(ttb, tb)
         record_call("wildcard_match", (nb, tb, kb, ttb))
         out = _dispatch(
             "wildcard_match",
@@ -335,13 +340,20 @@ def wildcard_match_host(ids: np.ndarray, lens: np.ndarray, templates: list[np.nd
     return np.asarray(wildcard_match(ids, lens, tmpl, tlens))
 
 
+# a launch costs about 2.5 ms of host work around it (PERF.md section 5):
+# a first-token group of at most this many lines is matched by the
+# kernel's numpy twin instead, bit-identical and in microseconds
+HOST_MAX_LINES = 8
+
+
 def match_first_bucketed(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]) -> np.ndarray:
     """Lowest-id matching template per line via the Pallas kernel, with
     first-token bucketing (the trie's root-level pruning) wired into the
     kernel path: instead of one dense N x K launch, templates are grouped
     by their first literal token and each bucket's kernel only sees the
-    lines that start with that token. Star-first templates run against
-    all lines. -> (N,) int32 assignment, -1 = none.
+    lines that start with that token (its numpy twin, for a bucket of at
+    most ``HOST_MAX_LINES`` lines). Star-first templates run against all
+    lines. -> (N,) int32 assignment, -1 = none.
     """
     n = ids.shape[0]
     n_tpl = len(templates)
@@ -360,7 +372,11 @@ def match_first_bucketed(ids: np.ndarray, lens: np.ndarray, templates: list[np.n
             buckets.setdefault(int(tpl[0]), []).append(k)
 
     def run(line_sel: np.ndarray, tidx: list[int]) -> None:
-        sub = wildcard_match_host(ids[line_sel], lens[line_sel], [templates[k] for k in tidx])
+        tpls = [templates[k] for k in tidx]
+        if len(line_sel) <= HOST_MAX_LINES:
+            sub = _wildcard_match_np(ids[line_sel], lens[line_sel], *pack_templates(tpls))
+        else:
+            sub = wildcard_match_host(ids[line_sel], lens[line_sel], tpls)
         any_m = sub.any(axis=1)
         if not any_m.any():
             return

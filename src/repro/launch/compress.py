@@ -98,28 +98,42 @@ def _cmd_pack(args) -> None:
 
 
 def _cmd_stream(args) -> None:
+    """With a format, the session's items are records: a header line and
+    the lines after it that parse as no header (``LogFormat.records``);
+    ``unpack`` joins them back with newlines, byte for byte."""
     from repro.core.codec import LogzipConfig
     from repro.core.stream import StreamingCompressor
+    from repro.core.tokenizer import LogFormat
 
     cfg = None if args.append else LogzipConfig(level=args.level, kernel=args.kernel,
                                                 format=args.format)
     f, close = _open_input(args.infile)
     raw = 0
+
+    def counted(lines):
+        nonlocal raw
+        for line in lines:
+            raw += len(line.encode("utf-8", "surrogateescape")) + 1
+            yield line
+
     try:
         with StreamingCompressor(args.outfile, cfg, chunk_lines=args.chunk_lines,
                                  chunk_bytes=args.chunk_bytes,
                                  append=args.append) as sc:
-            for line in _iter_lines(f):
-                raw += len(line.encode("utf-8", "surrogateescape")) + 1
-                sc.feed_line(line)
+            items = counted(_iter_lines(f))
+            if sc.cfg.format:
+                items = LogFormat(sc.cfg.format).records(items)
+            for item in items:
+                sc.feed_line(item)
             summary = sc.close()
     finally:
         if close:
             f.close()
     raw -= 1
     print(f"{raw/1e6:.2f} MB -> {summary['n_chunks']} chunks, "
-          f"{summary['n_lines']} total lines, {summary['n_templates']} templates, "
-          f"{summary['n_params']} params -> {args.outfile}")
+          f"{summary['n_lines']} total records, {summary['n_templates']} templates, "
+          f"{summary['n_params']} params, {summary['verbatim_records']} verbatim, "
+          f"{summary['wide_records_templated']} wide templated -> {args.outfile}")
 
 
 def _cmd_unpack(args) -> None:

@@ -2,6 +2,7 @@
 with range random access, inspect aggregation for all three magics."""
 
 import io
+import re
 import sys
 
 import pytest
@@ -177,8 +178,14 @@ def test_inspect_all_three_magics(log_file, tmp_path, monkeypatch, capsys):
     assert "line-weighted match_rate" in out
     assert "chunk   0: 300 lines" in out
 
+    # stream stores records: a line that opens no header (a malformed
+    # one here) continues the record before it
+    with open(log_file, encoding="utf-8") as f:
+        lines = f.read().split("\n")
+    n_records = sum(1 for i, s in enumerate(lines) if i == 0 or re.match(r"\S+ \S+ \S+ \S+: ", s))
+    assert n_records < 1200
     out = _run(["inspect", lzjs], monkeypatch, capsys)
-    assert "LZJS stream: 1200 lines in 4 chunks" in out
+    assert f"LZJS stream: {n_records} lines in 4 chunks" in out
     assert "session store:" in out
     assert "chunk   0" in out
 

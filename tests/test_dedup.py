@@ -12,6 +12,7 @@ from hypothesis import strategies as st
 from repro.core.codec import LogzipConfig, compress, decompress
 from repro.core.ise import ISEConfig
 from repro.data.loggen import DATASETS, generate_lines
+from strategies import SPARK_FORMAT, records
 
 FMT = "<Date> <Time> <Level> <Component>: <Content>"
 CFG_FAST = ISEConfig(min_sample=100, max_iters=3)
@@ -74,6 +75,22 @@ def test_dedup_identity_property(lines, dup_factor):
     a, b = _both(lines, cfg)
     assert a == b
     assert decompress(a) == lines
+
+
+record_text = records(line_text.filter(lambda s: len(s) < 40))
+
+
+@settings(max_examples=20, deadline=None)
+@given(st.lists(record_text, max_size=20), st.integers(1, 6))
+def test_dedup_identity_records_property(records_, dup_factor):
+    """Multi-line records: the dedup and reference paths agree byte for
+    byte, and the archive round-trips."""
+    records_ = (records_ * dup_factor)[:60]
+    cfg = LogzipConfig(level=3, kernel="none", format=SPARK_FORMAT,
+                       ise=ISEConfig(min_sample=20, max_iters=2))
+    a, b = _both(records_, cfg)
+    assert a == b
+    assert decompress(a) == records_
 
 
 def test_dedup_speedup_observable():

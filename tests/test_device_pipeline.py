@@ -20,6 +20,7 @@ from repro.core.match import (
     match_one_template_dp,
 )
 from repro.core.tokenizer import (
+    DEFAULT_DELIMITERS,
     LOG_FORMATS,
     TokenGrid,
     Vocab,
@@ -64,12 +65,15 @@ def test_tokenize_batch_matches_scalar_reference():
 
 
 def test_tokenize_batch_newline_content_falls_back():
-    contents = ["a b", "with\nnewline", "c,d"]
-    v1, v2 = Vocab(), Vocab()
-    g = tokenize_batch(contents, v1, 32)
-    r = _tokenize_batch_reference(contents, v2, 32, delimiters=" \t,;:=", tight=True)
-    assert v1._to_str == v2._to_str
-    assert _grids_equal(g, r, len(contents))
+    # a newline is a delimiter (multi-line records) on the batched path;
+    # a NUL, which the batch joins contents with, takes the scalar path
+    for contents in (["a b", "with\nnewline", "c,d"], ["a b", "with\x00nul", "c\n,d"]):
+        v1, v2 = Vocab(), Vocab()
+        g = tokenize_batch(contents, v1, 32)
+        r = _tokenize_batch_reference(contents, v2, 32, delimiters=DEFAULT_DELIMITERS,
+                                      tight=True)
+        assert v1._to_str == v2._to_str
+        assert _grids_equal(g, r, len(contents))
 
 
 def test_tokenize_batch_substring_matches_param_join():

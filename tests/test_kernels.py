@@ -58,6 +58,36 @@ def test_wildcard_match_matches_ref(n, t, k, tt):
     assert got.any(), "planted matches must register"
 
 
+def _plant_matches(rng, logs, lens, tmpl, tlens):
+    """Make line r an instance of template r (stars -> 1-2 tokens)."""
+    n, t = logs.shape
+    for r in range(min(n, len(tlens))):
+        row = []
+        for j in range(tlens[r]):
+            if tmpl[r, j] == 1:
+                row.extend([int(rng.integers(2, 24))] * int(rng.integers(1, 3)))
+            else:
+                row.append(int(tmpl[r, j]))
+        row = row[:t]
+        logs[r, :] = 0
+        logs[r, : len(row)] = row
+        lens[r] = len(row)
+
+
+# multi-line records: the 256 and 512 token buckets (BK=8 tiles at 512)
+@pytest.mark.parametrize("n,t,k,tt", [(130, 256, 20, 40), (70, 512, 11, 48), (20, 512, 3, 300)])
+def test_wildcard_match_wide_matches_ref(n, t, k, tt):
+    rng = np.random.default_rng(n * 11 + t)
+    logs, lens, tmpl, tlens = _rand_case(rng, n, t, k, tt, star_rate=0.1)
+    _plant_matches(rng, logs, lens, tmpl, tlens)
+    got = np.asarray(ops.wildcard_match(logs, lens, tmpl, tlens))
+    want = np.asarray(
+        wildcard_match_ref(jnp.asarray(logs), jnp.asarray(lens), jnp.asarray(tmpl), jnp.asarray(tlens))
+    )
+    np.testing.assert_array_equal(got, want)
+    assert got.any(), "planted matches must register"
+
+
 @settings(max_examples=60, deadline=None)
 @given(st.integers(1, 40), st.integers(1, 20), st.integers(1, 12), st.integers(1, 10), st.integers(0, 2**31 - 1))
 def test_wildcard_match_property(n, t, k, tt, seed):
@@ -109,6 +139,20 @@ def test_wildcard_match_odd_shapes(n, t, k, tt):
     want = np.asarray(
         wildcard_match_ref(jnp.asarray(logs), jnp.asarray(lens), jnp.asarray(tmpl), jnp.asarray(tlens))
     )
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n,t,k,tt", ODD_SHAPES + [(8, 128, 16, 16), (3, 512, 2, 300)])
+def test_wildcard_match_numpy_twin_matches_ref(n, t, k, tt):
+    """The twin ``match_first_bucketed`` runs for a first-token bucket of
+    at most ``HOST_MAX_LINES`` lines gives the kernel's oracle bits."""
+    rng = np.random.default_rng(n * 17 + tt)
+    logs, lens, tmpl, tlens = _rand_case(rng, n, t, k, tt, star_rate=0.35)
+    _plant_matches(rng, logs, lens, tmpl, tlens)
+    got = np.asarray(ops._wildcard_match_np(logs, lens, tmpl, tlens))
+    want = np.asarray(
+        wildcard_match_ref(jnp.asarray(logs), jnp.asarray(lens), jnp.asarray(tmpl), jnp.asarray(tlens))
+    ).astype(bool)
     np.testing.assert_array_equal(got, want)
 
 
@@ -201,5 +245,8 @@ def test_default_use_kernel_follows_platform(monkeypatch, platform, kernel_path)
     logs, lens, tmpl, tlens = _rand_case(rng, 20, 6, 3, 4)
     match_first(logs, lens, [tmpl[i, : tlens[i]] for i in range(3)],
                 use_kernel=None, dedup=False)
+    coltypes._transformed_stream(list(range(5, 5 + coltypes.KERNEL_MIN_VALUES)),
+                                 coltypes.MONOTONE_INT, None)
+    # a column shorter than the kernel's narrowest bucket stays on the host
     coltypes._transformed_stream([5, 7, 9], coltypes.MONOTONE_INT, None)
     assert calls == (["match", "delta"] if kernel_path else [])

@@ -16,6 +16,7 @@ from repro.core.ise import ISEConfig
 from repro.core.parallel import compress_parallel, decompress_parallel
 from repro.core.stream import StreamingCompressor, decompress_lzjs
 from repro.data.loggen import DATASETS, generate_lines
+from strategies import SPARK_FORMAT, records
 
 CFG_FAST = ISEConfig(min_sample=30, max_iters=2)
 
@@ -94,6 +95,7 @@ line_text = st.text(
 ).filter(lambda s: "\n" not in s)
 
 lines_strategy = st.lists(line_text, max_size=25)
+records_strategy = st.lists(records(line_text.filter(lambda s: len(s) < 30)), max_size=20)
 
 
 @settings(max_examples=25, deadline=None)
@@ -114,6 +116,26 @@ def test_roundtrip_property_with_format(lines, level, dedup_i):
                        ise=CFG_FAST, dedup=bool(dedup_i))
     blob, back = roundtrip(lines, cfg, "lzjs")
     assert back == lines
+
+
+@settings(max_examples=25, deadline=None)
+@given(records_strategy, st.integers(1, 3), st.integers(0, 1))
+def test_roundtrip_property_records(records_, level, dedup_i):
+    """Multi-line records against the Spark format: the header from the
+    first line, the rest in the content — lossless either way."""
+    cfg = LogzipConfig(level=level, format=SPARK_FORMAT, ise=CFG_FAST, dedup=bool(dedup_i))
+    blob, back = roundtrip(records_, cfg, "lzjs")
+    assert back == records_
+
+
+@settings(max_examples=25, deadline=None)
+@given(records_strategy, st.one_of(line_text, st.sampled_from(["\n\tat", "more\n", "INFO", "\n"])))
+def test_search_agrees_with_grep_records_property(records_, needle):
+    """A record matches when its text does, a needle across its lines too."""
+    cfg = LogzipConfig(level=3, format=SPARK_FORMAT, ise=CFG_FAST)
+    blob, _ = roundtrip(records_, cfg, "lzjs")
+    got = list(Q.search(blob, Q.Substring(needle)))
+    assert got == [(i, r) for i, r in enumerate(records_) if needle in r]
 
 
 @settings(max_examples=30, deadline=None)

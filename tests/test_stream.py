@@ -19,12 +19,16 @@ from repro.core.stream import (
 )
 from repro.core.templates import TemplateStore, extract_templates
 from repro.data.loggen import DATASETS, generate_lines
+from strategies import SPARK_FORMAT, records
 
 CFG_FAST = ISEConfig(min_sample=100, max_iters=2)
 
 line_text = st.text(alphabet=st.characters(blacklist_categories=("Cs",)), max_size=80).filter(
     lambda s: "\n" not in s
 )
+
+
+record_text = records(line_text.filter(lambda s: len(s) < 40))
 
 
 def _stream_blob(lines, cfg, **kw):
@@ -49,6 +53,17 @@ def test_streaming_equals_batch_property(lines, chunk_lines):
     assert decompress_lzjs(blob) == batch == lines
 
 
+@settings(max_examples=25, deadline=None)
+@given(st.lists(record_text, max_size=30), st.integers(1, 17))
+def test_streaming_equals_batch_records_property(records_, chunk_lines):
+    """Multi-line records (header line plus trace lines) through the
+    session decode to the same records as batch compress()."""
+    cfg = LogzipConfig(level=3, format=SPARK_FORMAT, ise=ISEConfig(min_sample=20, max_iters=2))
+    batch = decompress(compress(records_, cfg))
+    blob, _ = _stream_blob(records_, cfg, chunk_lines=chunk_lines)
+    assert decompress_lzjs(blob) == batch == records_
+
+
 @pytest.mark.parametrize("level", [1, 2, 3])
 def test_streaming_roundtrip_levels(level, spark_lines):
     cfg = LogzipConfig(level=level, format=DATASETS["Spark"]["format"], ise=CFG_FAST)
@@ -68,7 +83,8 @@ def test_streaming_chunk_bytes_budget(spark_lines):
 
 def test_streaming_empty_session():
     blob, summary = _stream_blob([], LogzipConfig(ise=CFG_FAST))
-    assert summary == {"n_lines": 0, "n_chunks": 0, "n_templates": 0, "n_params": 0}
+    assert summary == {"n_lines": 0, "n_chunks": 0, "n_templates": 0, "n_params": 0,
+                       "verbatim_records": 0, "wide_records_templated": 0}
     assert decompress_lzjs(blob) == []
     assert list(iter_stream(io.BytesIO(blob))) == []
 

@@ -51,7 +51,9 @@ def _compiled_text(fn, one_chip, *shapes, dtypes=()) -> str:
     (256, 32, 16, 16),
     (256, 32, 64, 16),
     (4096, 32, 256, 16),
-    (8192, 128, 256, 128),  # widest token buckets: the largest VMEM tile
+    (8192, 128, 256, 128),  # widest one-line token buckets
+    (1024, 256, 64, 256),   # multi-line records (stack traces)
+    (1024, 512, 64, 512),   # the token budget: the largest VMEM tile (BK=8)
 ])
 def test_wildcard_match_compiles(one_chip, n, t, k, tt):
     txt = _compiled_text(
