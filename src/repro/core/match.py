@@ -25,11 +25,13 @@ the shortest span; any valid alignment is lossless — the tie-break only
 fixes determinism).
 
 ``match_first`` assigns each line the lowest-id matching template —
-the production-canonical assignment. First-token bucketing (the trie's
-root-level pruning) cuts the candidate template set per line, and exact
-duplicate (ids, len) rows are collapsed before the DP runs — matching is
-deterministic per row, so the result is identical, but real logs are
-dominated by repeats and only pay for distinct lines.
+the production-canonical assignment. On the host, first-token bucketing
+(the trie's root-level pruning) cuts the candidate template set per
+line; the kernel path matches the dense grid in one launch
+(``ops.match_first_dense``). Exact duplicate (ids, len) rows are
+collapsed before either runs — matching is deterministic per row, so
+the result is identical, but real logs are dominated by repeats and
+only pay for distinct lines.
 
 Rows are matched at the width of their own token bucket (``width_groups``):
 one multi-line record of 400 tokens must not make every one-line record
@@ -272,11 +274,13 @@ def match_first(
 ) -> np.ndarray:
     """Assign each line the lowest-id matching template (-1 = none).
 
-    Templates are bucketed by first token (literal or '*') like the trie
-    root, so each line only runs the DP against plausible candidates.
-    With ``dedup`` (default) duplicate (ids, len) rows are matched once
-    and the assignment is broadcast back — bit-identical results, and the
-    DP only pays for distinct lines. ``use_kernel=None`` runs the Pallas
+    On the numpy path templates are bucketed by first token (literal or
+    '*') like the trie root, so each line only runs the DP against
+    plausible candidates; the kernel path makes one launch of all lines
+    against all templates per token bucket. With ``dedup`` (default)
+    duplicate (ids, len) rows are matched once and the assignment is
+    broadcast back — bit-identical results, and the DP only pays for
+    distinct lines. ``use_kernel=None`` runs the Pallas
     kernel path on a TPU backend and the numpy path elsewhere. Wider
     than ``NARROW_TOKENS``, each token bucket (``width_groups``) is
     matched at its own width against the templates that fit it (a
@@ -318,7 +322,7 @@ def _match_first(ids, lens, templates, use_kernel, dedup) -> np.ndarray:
         from repro.kernels import ops as kops
 
         if use_kernel or kops.on_tpu():
-            return kops.match_first_bucketed(ids, lens, templates)
+            return kops.match_first_dense(ids, lens, templates)
 
     first_tok = ids[:, 0]
     for k, tpl in enumerate(templates):

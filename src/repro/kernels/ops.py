@@ -15,8 +15,15 @@ not seen) lands as ``dispatch.<kernel>.compile`` in the caller's sink.
 Once the padded bucket is launched, no eager JAX op may run on an
 unpadded shape: slicing a ``jax.Array`` compiles a ``dynamic_slice``
 module per distinct output shape, and the unpadded shapes follow the
-data (one call per first-token group or per column), so no warm-up
+data (distinct lines per chunk, values per column), so no warm-up
 covers them. Bucketing only pays if the trim happens on the host.
+
+A launch costs milliseconds of host work and microseconds of device
+time, so ``match_first_dense`` matches one token bucket's lines against
+all the templates in one padded ``wildcard_match`` launch, not one per
+first-token group: the chip does the dense N x K grid, the host one
+launch. A call of more than ``MAX_LINES`` lines (a batch ``pack``) makes
+one such launch per ``MAX_LINES`` lines, so its cost stays bounded.
 
 ``wildcard_match_sharded`` is the pod-scale matcher: logs sharded over
 the mesh ``data`` axis, templates replicated — zero-collective data
@@ -276,16 +283,15 @@ def wildcard_match(logs, lens, templates, t_lens, *, use_buckets: bool = True) -
     n, t = logs.shape
     k, tt = templates.shape
     if use_buckets:
-        # floors absorb the normal drift of a streaming session (token
-        # width wobbling per chunk, the template store creeping past a
-        # power of two) so warm sessions never leave their bucket
-        nb, tb = bucket(n, 256), bucket(t, 32)
-        kb, ttb = bucket(k, 16), bucket(tt, 16)
-        if tb > 128:
-            # multi-line records: one shape per token bucket, so that a
-            # session's warm-up compiles it (the kernel stops at each
-            # template tile's longest template: the padding adds no steps)
-            kb, ttb = bucket(k, 32), max(ttb, tb)
+        # floors absorb the normal drift of a streaming session (distinct
+        # lines per chunk, token width wobbling per chunk, the template
+        # store creeping past a power of two) so warm sessions never leave
+        # their bucket; templates are padded to the log bucket, one shape
+        # per token bucket. A tile of padding runs no DP step (the kernel's
+        # tiles stop at their longest template and their longest line); it
+        # still pays its block copy and the final reduction.
+        nb, tb = bucket(n, 1024), bucket(t, 32)
+        kb, ttb = bucket(k, 128), max(tb, bucket(tt, 16))
         record_call("wildcard_match", (nb, tb, kb, ttb))
         out = _dispatch(
             "wildcard_match",
@@ -296,7 +302,7 @@ def wildcard_match(logs, lens, templates, t_lens, *, use_buckets: bool = True) -
         )
         # the padded width tb would let stars absorb PAD columns of lines
         # whose true length exceeds t: re-apply the host's truncation rule
-        return np.asarray(out)[:n, :k].astype(bool) & (lens_np <= t)[:, None]
+        return np.asarray(out)[:n, :k].astype(bool, copy=False) & (lens_np <= t)[:, None]
     out = _dispatch(
         "wildcard_match",
         jnp.asarray(logs), jnp.asarray(lens_np), jnp.asarray(templates),
@@ -332,67 +338,37 @@ def pack_templates(templates: list[np.ndarray], t_max: int | None = None) -> tup
     return mat, lens
 
 
-def wildcard_match_host(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]) -> np.ndarray:
-    """numpy in/out convenience used by ``core.match.match_first``."""
-    tmpl, tlens = pack_templates(templates)
-    if tmpl.shape[0] == 0:
-        return np.zeros((ids.shape[0], 0), bool)
-    return np.asarray(wildcard_match(ids, lens, tmpl, tlens))
+# a stream chunk's distinct lines fit one launch; a larger call (a batch
+# ``pack`` matches a whole file's distinct lines) is cut into launches of
+# this many lines, so the device's (K, N) result and the host's copy of it
+# stay bounded by MAX_LINES x K
+MAX_LINES = 8192
 
 
-# a launch costs about 2.5 ms of host work around it (PERF.md section 5):
-# a first-token group of at most this many lines is matched by the
-# kernel's numpy twin instead, bit-identical and in microseconds
-HOST_MAX_LINES = 8
+def match_first_dense(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]) -> np.ndarray:
+    """Lowest-id matching template per line, from one ``wildcard_match``
+    launch of the lines against all the templates per ``MAX_LINES``
+    lines -> (N,) int32 assignment, -1 = none.
 
-
-def match_first_bucketed(ids: np.ndarray, lens: np.ndarray, templates: list[np.ndarray]) -> np.ndarray:
-    """Lowest-id matching template per line via the Pallas kernel, with
-    first-token bucketing (the trie's root-level pruning) wired into the
-    kernel path: instead of one dense N x K launch, templates are grouped
-    by their first literal token and each bucket's kernel only sees the
-    lines that start with that token (its numpy twin, for a bucket of at
-    most ``HOST_MAX_LINES`` lines). Star-first templates run against all
-    lines. -> (N,) int32 assignment, -1 = none.
+    A launch costs milliseconds of host work around microseconds of
+    device time (PERF.md section 5), so the dense N x K grid in one
+    launch beats pruning by first token with one launch per group: a
+    template whose first unit is a literal only matches lines that start
+    with it, so the dense matrix is True where the groups' launches were
+    and the lowest id is the same. Templates with more units than the
+    lines' width (which no line can match) and empty templates (which
+    match nothing, on the host too) get the ``t_len = -1`` sentinel.
     """
     n = ids.shape[0]
-    n_tpl = len(templates)
-    best = np.full((n,), n_tpl, np.int64)  # sentinel: no match
-    if n == 0 or n_tpl == 0:
-        return np.full((n,), -1, np.int32)
-
-    buckets: dict[int, list[int]] = {}
-    star_bucket: list[int] = []
-    for k, tpl in enumerate(templates):
-        if len(tpl) == 0:
-            continue  # empty templates match nothing (host semantics)
-        if int(tpl[0]) == STAR_ID:
-            star_bucket.append(k)
-        else:
-            buckets.setdefault(int(tpl[0]), []).append(k)
-
-    def run(line_sel: np.ndarray, tidx: list[int]) -> None:
-        tpls = [templates[k] for k in tidx]
-        if len(line_sel) <= HOST_MAX_LINES:
-            sub = _wildcard_match_np(ids[line_sel], lens[line_sel], *pack_templates(tpls))
-        else:
-            sub = wildcard_match_host(ids[line_sel], lens[line_sel], tpls)
-        any_m = sub.any(axis=1)
-        if not any_m.any():
-            return
-        # tidx is ascending, argmax picks the first True -> lowest id in bucket
-        cand = np.asarray(tidx, np.int64)[sub.argmax(axis=1)]
-        rows = line_sel[any_m]
-        best[rows] = np.minimum(best[rows], cand[any_m])
-
-    first_tok = ids[:, 0] if ids.shape[1] else np.zeros((n,), np.int32)
-    for f, tidx in buckets.items():
-        sel = np.nonzero(first_tok == f)[0]
-        if len(sel):
-            run(sel, tidx)
-    if star_bucket:
-        run(np.arange(n), star_bucket)
-    return np.where(best < n_tpl, best, -1).astype(np.int32)
+    out = np.full((n,), -1, np.int32)
+    if n == 0 or not templates:
+        return out
+    tmpl, t_lens = pack_templates(templates, t_max=ids.shape[1])
+    t_lens[t_lens == 0] = -1
+    for s in range(0, n, MAX_LINES):
+        hit = wildcard_match(ids[s : s + MAX_LINES], lens[s : s + MAX_LINES], tmpl, t_lens)
+        out[s : s + MAX_LINES] = np.where(hit.any(axis=1), hit.argmax(axis=1), -1)
+    return out
 
 
 _SHARDED_CACHE: dict[tuple, object] = {}

@@ -17,8 +17,11 @@ prefix-OR is built from log-step shifted ORs (Mosaic has no ``cumsum``);
 it costs several times a literal step, so it runs only on the steps
 where some template of the tile has a '*' (flags per template tile,
 prefetched to SMEM with the tile's step count: the loop stops at the
-tile's longest template). A stack trace's template is hundreds of
-literal frames with a few stars. Template token ``j`` is read from the
+tile's longest template, or at the line tile's longest line, since a
+template of m units needs m tokens, so a tile of padding on either axis
+runs no DP step; it still pays its block copy and the final reduction).
+A stack trace's template is hundreds of literal frames
+with a few stars. Template token ``j`` is read from the
 ref (``tmpl_ref[j]``, a ``(BK, 1)`` column), never by slicing a loaded
 value. Templates shorter than Tt freeze their column via the
 ``j < t_len`` select; a ``t_len < 0`` sentinel (padding rows,
@@ -62,13 +65,17 @@ def block_k(t: int) -> int:
     return BK if t <= 256 else BK // 2
 
 
-def _match_kernel(steps_ref, star_ref, logs_ref, lens_ref, tmpl_ref, tlen_ref, out_ref):
+def _match_kernel(steps_ref, star_ref, longest_ref, logs_ref, lens_ref, tmpl_ref, tlen_ref,
+                  out_ref):
     logs = logs_ref[...]            # (T, BN) token position x line
     lens = lens_ref[...]            # (1, BN)
     tlens = tlen_ref[...]           # (BK, 1)
     t, bn = logs.shape
     bk = tlens.shape[0]
     kt = pl.program_id(1)
+    # a template of m units needs at least m tokens: the tile stops at its
+    # longest line too, so a tile of padding lines takes no step
+    longest = longest_ref[pl.program_id(0)]
 
     def shift(x, s):                # x[i - s] along the position axis, 0 below
         return jnp.concatenate([jnp.zeros((s,) + x.shape[1:], x.dtype), x[:-s]], axis=0)
@@ -92,11 +99,13 @@ def _match_kernel(steps_ref, star_ref, logs_ref, lens_ref, tmpl_ref, tlen_ref, o
         return jnp.where(j < tlens[None], new, col)               # template still has tokens
 
     pos = jax.lax.broadcasted_iota(jnp.int32, (t + 1, bk, bn), 0)
-    col = jax.lax.fori_loop(0, steps_ref[kt], per_token, (pos == 0).astype(jnp.int32))
+    col = jax.lax.fori_loop(0, jnp.minimum(steps_ref[kt], longest), per_token,
+                            (pos == 0).astype(jnp.int32))
 
     hit = jnp.sum(jnp.where(pos == lens[None], col, 0), axis=0)  # col[i = len(log)]
     hit = hit * (lens <= t).astype(jnp.int32)                    # truncated lines: no match
-    out_ref[...] = hit * (tlens >= 0).astype(jnp.int32)          # sentinel templates: no match
+    # sentinel templates, and those longer than every line: no match
+    out_ref[...] = hit * ((tlens >= 0) & (tlens <= longest)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -132,11 +141,13 @@ def wildcard_match(
     tiles = (k + k_pad) // bk
     steps = jnp.minimum(jnp.max(tlen_p.reshape(tiles, bk), axis=1), tt).astype(jnp.int32)
     star = jnp.any((tmpl_p == STAR_ID).reshape(tiles, bk, tt), axis=1).astype(jnp.int32)
+    # per line tile: its longest line
+    longest = jnp.max(lens_p.reshape(-1, BN), axis=1).astype(jnp.int32)
     out = pl.pallas_call(
         _match_kernel,
         out_shape=jax.ShapeDtypeStruct((k + k_pad, n + n_pad), jnp.int32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=((n + n_pad) // BN, tiles),
             in_specs=[
                 pl.BlockSpec((t, BN), lambda i, j, *_: (0, i)),
@@ -147,5 +158,5 @@ def wildcard_match(
             out_specs=pl.BlockSpec((bk, BN), lambda i, j, *_: (j, i)),
         ),
         interpret=interpret,
-    )(steps, star, logs_t, lens_p, tmpl_t, tlen_p.reshape(-1, 1))
+    )(steps, star, longest, logs_t, lens_p, tmpl_t, tlen_p.reshape(-1, 1))
     return out[:k, :n].T.astype(jnp.int8)

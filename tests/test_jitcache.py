@@ -65,7 +65,7 @@ def test_wildcard_match_trace_count_stable_within_bucket():
     jitcache.reset_counters()
     rng = np.random.default_rng(2)
     base = jitcache.TRACE_COUNTS["wildcard_match"]
-    # drifting shapes, same buckets: floors are (N 256, T 32, K 16, Tt 16)
+    # drifting shapes, same buckets: floors are (N 1024, T 32, K 128, Tt 32)
     for n, t, k, tt in [(100, 7, 3, 4), (180, 8, 5, 5), (256, 6, 8, 3), (31, 5, 2, 2)]:
         logs, lens, tmpl, tlens = _case(rng, n, t, k, tt)
         ops.wildcard_match(logs, lens, tmpl, tlens)
@@ -109,7 +109,7 @@ def _match_extract_call(rng, n, k, use_buckets):
 
 
 @pytest.mark.parametrize("call, shapes", [
-    # every shape of a case shares one bucket: wildcard_match (N 256, K 16),
+    # every shape of a case shares one bucket: wildcard_match (N 1024, K 128),
     # delta_zigzag (R 8, C 128), match_extract (N 64, K 16)
     (_wildcard_call, [(100, 3), (37, 9), (250, 16), (5, 1)]),
     (_colcodec_call, [(1, 40), (3, 97), (8, 128), (2, 5)]),

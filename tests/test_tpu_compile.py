@@ -3,9 +3,11 @@
 Interpret-mode tests cannot see what the TPU compiler refuses: blocks
 that are not tiled to (8, 128), primitives Mosaic does not lower, more
 VMEM than a core's scoped limit. Each test here compiles one kernel at
-a bucket shape the pipeline launches (``jitcache.bucket_stats`` of a
-kernel-path HDFS session; K = 256 is the largest template bucket it
-reached) and checks that the compiled program holds the Mosaic kernel.
+a bucket shape the pipeline launches and checks that the compiled
+program holds the Mosaic kernel. ``wildcard_match`` launches one token
+bucket's distinct lines of a chunk against every template: up to 8,192
+lines (a chunk) and 1,024 templates, with templates padded to the token
+bucket.
 """
 
 import jax
@@ -54,6 +56,9 @@ def _compiled_text(fn, one_chip, *shapes, dtypes=()) -> str:
     (8192, 128, 256, 128),  # widest one-line token buckets
     (1024, 256, 64, 256),   # multi-line records (stack traces)
     (1024, 512, 64, 512),   # the token budget: the largest VMEM tile (BK=8)
+    (1024, 32, 128, 32),    # the floor of a dense launch
+    (8192, 64, 1024, 64),   # a chunk's distinct lines against a large store
+    (8192, 128, 1024, 128),
 ])
 def test_wildcard_match_compiles(one_chip, n, t, k, tt):
     txt = _compiled_text(
